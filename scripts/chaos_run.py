@@ -33,15 +33,7 @@ import zlib
 
 import numpy as np
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-from repro import telemetry as T  # noqa: E402
-from repro.core import local_context  # noqa: E402
-from repro.dataframe.frame import DataFrame  # noqa: E402
-from repro.io.dataset import write_dataset  # noqa: E402
-from repro.io.scan import pred  # noqa: E402
-from repro.plan.frame import LazyFrame  # noqa: E402
-from repro.resilience import FaultPolicy, arm_schedule, faults  # noqa: E402
+SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def _crc_rows(df) -> str:
@@ -53,6 +45,8 @@ def _crc_rows(df) -> str:
 
 
 def _events(root: str, n: int = 96) -> str:
+    from repro.io.dataset import write_dataset
+
     rng = np.random.default_rng(5)
     cols = {"k": (np.arange(n) % 12).astype(np.float32),
             "u": np.arange(n, dtype=np.float32),
@@ -62,6 +56,9 @@ def _events(root: str, n: int = 96) -> str:
 
 
 def _pipeline(ds: str, ctx):
+    from repro.io.scan import pred
+    from repro.plan.frame import LazyFrame
+
     return (LazyFrame.read_parquet(ds, ctx)
             .filter([pred("u", "<", 72.0)])
             .groupby(["k"], [("v", "sum"), ("v", "count")])
@@ -70,6 +67,10 @@ def _pipeline(ds: str, ctx):
 
 def scenario_scan(seed: int, work: str) -> str:
     """Transient scan faults under a seeded schedule; retry absorbs."""
+    from repro import telemetry as T
+    from repro.core import local_context
+    from repro.resilience import FaultPolicy, arm_schedule, faults
+
     ctx = local_context()
     ds = _events(os.path.join(work, "ds"))
     oracle = _crc_rows(_pipeline(ds, ctx).collect(strict=False))
@@ -92,6 +93,10 @@ def scenario_scan(seed: int, work: str) -> str:
 
 def scenario_spill(seed: int, work: str) -> str:
     """Spill write faults; policy retry leaves no torn runs, bit-exact."""
+    from repro import telemetry as T
+    from repro.core import local_context
+    from repro.dataframe.frame import DataFrame
+    from repro.resilience import FaultPolicy, arm_schedule, faults
     from repro.spill import spill_groupby
 
     ctx = local_context()
@@ -147,9 +152,12 @@ lf = (LazyFrame.read_parquet(ds, ctx)
       .groupby(["k"], [("v", "sum"), ("v", "count")])
       .sort_values("v_sum"))
 rec = T.Collector("chaos-child")
-pol = FaultPolicy(max_retries=1, checkpoint_dir=ckdir,
-                  keep_checkpoints=True)
-out = lf.collect(strict=False, policy=pol, telemetry=rec)
+if ckdir == "-":  # the uninterrupted oracle: no policy, no stages
+    out = lf.collect(strict=False)
+else:
+    pol = FaultPolicy(max_retries=1, checkpoint_dir=ckdir,
+                      keep_checkpoints=True)
+    out = lf.collect(strict=False, policy=pol, telemetry=rec)
 d = out.to_numpy()
 crc = 0
 for k in sorted(d):
@@ -159,30 +167,34 @@ print("CRC", f"{{crc:08x}}")
 """
 
 
+def _child(args, env, expect_rc: int = 0) -> dict:
+    r = subprocess.run([sys.executable] + args, capture_output=True,
+                       text=True, timeout=560, env=env)
+    assert r.returncode == expect_rc, (
+        f"expected rc={expect_rc}, got rc={r.returncode}\n"
+        f"{r.stderr[-2000:]}")
+    pairs = (line.split(None, 1) for line in r.stdout.splitlines())
+    return dict(p for p in pairs if len(p) == 2)
+
+
 def scenario_commit_crash(seed: int, work: str) -> str:
-    """SIGKILL mid stage-commit in a child; resume is bit-exact."""
-    ctx = local_context()
-    ds = _events(os.path.join(work, "ds"))
-    oracle = _crc_rows(_pipeline(ds, ctx).collect(strict=False))
+    """SIGKILL mid stage-commit in a child; resume is bit-exact.
+
+    Runs in the parent without touching JAX: the dataset, the oracle and
+    both attempts each run in a child, so a device is only ever held by
+    one process at a time."""
+    ds = os.path.join(work, "ds")
     ckdir = os.path.join(work, "stages")
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "..", "src")
-    child = _CHILD.format(src=os.path.abspath(src))
+    child = _CHILD.format(src=SRC)
     env = dict(os.environ)
     env.pop("HPTMT_FAULTS", None)
+    _child([os.path.abspath(__file__), "--make-events", ds], env)
+    oracle = _child(["-c", child, ds, "-"], env)["CRC"]
     # the pipeline commits two stages; the seed picks which commit dies
     nth = 1 + (seed >> 1) % 2
     env1 = dict(env, HPTMT_FAULTS=f"checkpoint.commit:crash:{nth}")
-    r1 = subprocess.run([sys.executable, "-c", child, ds, ckdir],
-                        capture_output=True, text=True, timeout=560,
-                        env=env1)
-    assert r1.returncode == -9, (
-        f"expected SIGKILL, got rc={r1.returncode}\n{r1.stderr[-2000:]}")
-    r2 = subprocess.run([sys.executable, "-c", child, ds, ckdir],
-                        capture_output=True, text=True, timeout=560,
-                        env=env)
-    assert r2.returncode == 0, r2.stderr[-2000:]
-    lines = dict(l.split() for l in r2.stdout.splitlines())
+    _child(["-c", child, ds, ckdir], env1, expect_rc=-9)
+    lines = _child(["-c", child, ds, ckdir], env)
     assert lines["CRC"] == oracle, (
         f"resumed run diverged: {lines['CRC']} != oracle {oracle}")
     restored = int(lines["RESTORED"])
@@ -191,8 +203,18 @@ def scenario_commit_crash(seed: int, work: str) -> str:
     return f"killed_at_commit={nth} restored={restored} crc=ok"
 
 
-SCENARIOS = [("scan", scenario_scan), ("spill", scenario_spill),
-             ("commit-crash", scenario_commit_crash)]
+def _in_child(name: str, seed: int, work: str) -> str:
+    """Run one in-process scenario in a child, so the parent never holds
+    a device while later scenarios start their own children."""
+    out = _child([os.path.abspath(__file__), "--run", name, "--seeds",
+                  str(seed), "--work", work], dict(os.environ))
+    if "FAIL" in out:
+        raise AssertionError(out["FAIL"])
+    return out["PASS"]
+
+
+IN_PROCESS = {"scan": scenario_scan, "spill": scenario_spill}
+SCENARIOS = ("scan", "spill", "commit-crash")
 
 
 def main(argv=None) -> int:
@@ -201,16 +223,32 @@ def main(argv=None) -> int:
                     help="comma-separated chaos schedule seeds")
     ap.add_argument("--only", default=None,
                     help="run one scenario: scan | spill | commit-crash")
+    ap.add_argument("--run", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--work", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--make-events", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
+    sys.path.insert(0, SRC)
+    if args.make_events:
+        _events(args.make_events)
+        return 0
     seeds = [int(s) for s in args.seeds.split(",") if s]
+    if args.run:  # child side of _in_child
+        try:
+            print("PASS", IN_PROCESS[args.run](seeds[0], args.work))
+        except AssertionError as e:
+            print("FAIL", " ".join(str(e).split()))
+        return 0
     failures = 0
     for seed in seeds:
-        for name, fn in SCENARIOS:
+        for name in SCENARIOS:
             if args.only and name != args.only:
                 continue
             work = tempfile.mkdtemp(prefix=f"chaos-{name}-{seed}-")
             try:
-                detail = fn(seed, work)
+                if name in IN_PROCESS:
+                    detail = _in_child(name, seed, work)
+                else:
+                    detail = scenario_commit_crash(seed, work)
                 print(f"PASS {name:>12} seed={seed:<3} {detail}")
             except AssertionError as e:
                 failures += 1

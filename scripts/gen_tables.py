@@ -18,9 +18,10 @@ def _tpu_adjusted(r):
         return roof["tpu_adjusted"]
     cfg = get_config(r["arch"])
     cell = SHAPES[r["shape"]]
+    chip = rl.peaks(rl.DRYRUN_KIND)
     meas = rl.Roofline(
-        flops=roof["compute_s"] * rl.PEAK_FLOPS,
-        hbm_bytes=roof["memory_s"] * rl.HBM_BW,
+        flops=roof["compute_s"] * chip.bf16_flops,
+        hbm_bytes=roof["memory_s"] * chip.hbm_bw,
         collectives=rl.CollectiveStats({}, {}, roof["collective_s"]),
         n_chips=r["n_chips"], model_flops=roof["model_flops"])
     return rl.tpu_adjusted_terms(cfg, cell, r["n_chips"], meas)

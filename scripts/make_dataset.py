@@ -25,6 +25,43 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "src"))
 
 
+def make_events_arrays(n_rows: int = 100_000, n_users: int = 1_000,
+                       n_days: int = 30, seed: int = 0):
+    """The events fact table and users dimension table as numpy columns:
+    ``(events, users)``.  Events are sorted by ``day``; ``users.user_id``
+    is ``arange(n_users)``."""
+    rng = np.random.default_rng(seed)
+    events = {
+        "user_id": rng.integers(0, n_users, n_rows).astype(np.int32),
+        "day": np.sort(rng.integers(0, n_days, n_rows)).astype(np.int32),
+        "value": rng.normal(size=n_rows).astype(np.float32),
+        "score": rng.uniform(0, 1, n_rows).astype(np.float32),
+        "clicks": rng.integers(0, 20, n_rows).astype(np.int32),
+        "flag": (rng.uniform(size=n_rows) < 0.3),
+    }
+    users = {
+        "user_id": np.arange(n_users, dtype=np.int32),
+        "segment": rng.integers(0, 8, n_users).astype(np.int32),
+        "weight": rng.uniform(0.5, 2.0, n_users).astype(np.float32),
+    }
+    return events, users
+
+
+def write_events_dataset(root: str, events, users, fmt=None,
+                         rows_per_group: int = None) -> str:
+    """Write :func:`make_events_arrays` output as ``root/events`` and
+    ``root/users`` datasets."""
+    from repro.io import write_dataset
+
+    n_rows = events["user_id"].shape[0]
+    per = rows_per_group or max(n_rows // 16, 1)
+    write_dataset(os.path.join(root, "events"), [(events, n_rows)],
+                  format=fmt, rows_per_group=per)
+    write_dataset(os.path.join(root, "users"),
+                  [(users, users["user_id"].shape[0])], format=fmt)
+    return root
+
+
 def make_events_dataset(root: str, n_rows: int = 100_000,
                         n_users: int = 1_000, n_days: int = 30,
                         fmt=None, rows_per_group: int = None,
@@ -34,28 +71,9 @@ def make_events_dataset(root: str, n_rows: int = 100_000,
     Events are sorted by ``day`` so per-fragment min/max statistics make
     day-range predicates prunable — the pushdown demo/benchmark shape.
     """
-    from repro.io import write_dataset
-
-    rng = np.random.default_rng(seed)
-    per = rows_per_group or max(n_rows // 16, 1)
-    events = {
-        "user_id": rng.integers(0, n_users, n_rows).astype(np.int32),
-        "day": np.sort(rng.integers(0, n_days, n_rows)).astype(np.int32),
-        "value": rng.normal(size=n_rows).astype(np.float32),
-        "score": rng.uniform(0, 1, n_rows).astype(np.float32),
-        "clicks": rng.integers(0, 20, n_rows).astype(np.int32),
-        "flag": (rng.uniform(size=n_rows) < 0.3),
-    }
-    write_dataset(os.path.join(root, "events"), [(events, n_rows)],
-                  format=fmt, rows_per_group=per)
-    users = {
-        "user_id": np.arange(n_users, dtype=np.int32),
-        "segment": rng.integers(0, 8, n_users).astype(np.int32),
-        "weight": rng.uniform(0.5, 2.0, n_users).astype(np.float32),
-    }
-    write_dataset(os.path.join(root, "users"), [(users, n_users)],
-                  format=fmt)
-    return root
+    events, users = make_events_arrays(n_rows, n_users, n_days, seed)
+    return write_events_dataset(root, events, users, fmt=fmt,
+                                rows_per_group=rows_per_group)
 
 
 def make_corpus_dataset(root: str, n_docs: int = 64, mean_doc_len: int = 96,

@@ -66,7 +66,7 @@ class ModelConfig:
     mlstm_chunk: int = 128          # mLSTM chunkwise-parallel chunk length
     attn_q_chunk: int = 256         # XLA-attention query streaming chunk
     scan_unroll: bool = False       # unroll layer-group scan (roofline runs)
-    use_flash: Optional[bool] = None  # None → Pallas on TPU, XLA elsewhere
+    use_flash: Optional[bool] = None  # None → kernels/dispatch.py rule
     mla_absorb: bool = False        # absorbed MLA decode (beyond-paper opt)
     kv_quant: bool = False          # int8 KV cache w/ per-vector scales
 

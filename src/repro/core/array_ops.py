@@ -52,13 +52,6 @@ _REDUCERS = {
 }
 
 
-def axis_size(axis: AxisName):
-    """Size of a named mesh axis inside SPMD code (jax-version compatible)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)  # folds to a constant
-
-
 # ---------------------------------------------------------------------------
 # in-SPMD collectives (usable inside shard_map)
 # ---------------------------------------------------------------------------
@@ -111,7 +104,7 @@ def spmd_gather(x, axis: str, root: int = 0):
 
 def spmd_scatter(x, axis: str, root: int = 0):
     """Rooted scatter: root's buffer split into blocks across the axis."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     full = spmd_broadcast(x, axis, root=root)
     idx = jax.lax.axis_index(axis)
     piece = x.shape[0] // n

@@ -23,16 +23,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def compat_shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions (experimental module pre-0.5)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 def make_mesh(shape: Sequence[int], names: Sequence[str], devices=None) -> Mesh:
     """Create a mesh with ``Auto`` axis types (shard_map-compatible)."""
     if devices is None:
@@ -112,8 +102,8 @@ class HPTMTContext:
         """shard_map over this context's mesh (identity when single-device)."""
         if self.mesh is None:
             raise ValueError("shard_map requires a mesh-backed context")
-        return compat_shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=check_vma)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check_vma)
 
 
 def local_context() -> HPTMTContext:

@@ -104,20 +104,24 @@ def _u32_to_col(u: jnp.ndarray, dtype, trailing: Tuple[int, ...]) -> jnp.ndarray
 
 
 def pack_columns(cols: Cols) -> Tuple[jnp.ndarray, Tuple[ColSpec, ...]]:
-    """Pack all columns into one ``(cap, row_width)`` uint32 buffer."""
+    """Pack all columns into one ``(row_width, cap)`` uint32 buffer.
+
+    Lanes-major: one buffer row per lane, table rows along the last axis.
+    A TPU tiles the last axis by 128, so a ``(cap, row_width)`` buffer
+    with a row width of ~10 would occupy ~12x its bytes in HBM."""
     parts, specs, start = [], [], 0
     for name in sorted(cols):
         u = _col_to_u32(cols[name])
         specs.append(ColSpec(name, cols[name].dtype,
                              tuple(cols[name].shape[1:]), start, u.shape[1]))
         start += u.shape[1]
-        parts.append(u)
-    return jnp.concatenate(parts, axis=1), tuple(specs)
+        parts.append(u.T)
+    return jnp.concatenate(parts, axis=0), tuple(specs)
 
 
 def unpack_columns(buf: jnp.ndarray, specs: Sequence[ColSpec]) -> Cols:
     """Recover original dtypes/shapes from a packed uint32 buffer."""
-    return {s.name: _u32_to_col(buf[:, s.start:s.start + s.lanes],
+    return {s.name: _u32_to_col(buf[s.start:s.start + s.lanes].T,
                                 s.dtype, s.trailing) for s in specs}
 
 
@@ -139,10 +143,10 @@ def dest_ranks(dest: jnp.ndarray, n_parts: int,
     rank = jnp.zeros((n,), jnp.int32)
     for c0 in range(0, n_parts, chunk):
         parts = jnp.arange(c0, min(c0 + chunk, n_parts), dtype=dest.dtype)
-        onehot = dest[:, None] == parts[None, :]
-        prefix = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1
-        idx = jnp.clip(dest.astype(jnp.int32) - c0, 0, parts.shape[0] - 1)
-        picked = jnp.take_along_axis(prefix, idx[:, None], axis=1)[:, 0]
+        # (chunk, n): rows along the last axis, the TPU's 128-wide one
+        onehot = parts[:, None] == dest[None, :]
+        prefix = jnp.cumsum(onehot.astype(jnp.int32), axis=1) - 1
+        picked = jnp.sum(jnp.where(onehot, prefix, 0), axis=0)
         in_chunk = (dest >= c0) & (dest < c0 + parts.shape[0])
         rank = jnp.where(in_chunk, picked, rank)
     return rank
@@ -180,39 +184,41 @@ def exchange_rows(cols: Cols, dest: jnp.ndarray, n_shards: int, bucket: int,
 
     Frame layout (DESIGN.md §3.2): per destination, ``bucket`` packed data
     rows followed by one metadata row whose lane 0 holds the send count —
-    so counts ride the same collective as the data.
+    so counts ride the same collective as the data.  The buffer is
+    lanes-major (``pack_columns``), so the AllToAll splits its last axis.
 
-    Returns ``(received_cols, received_valid_mask, n_overflowed_send)``.
+    Returns ``(received_cols, received_valid_mask, n_overflowed_send)``;
+    on a mesh the received columns include the metadata rows, which the
+    mask never marks valid.
     """
     if hist is None:
         hist = jnp.zeros(n_shards + 1, jnp.int32).at[
             jnp.clip(dest, 0, n_shards)].add(1)[:n_shards]
     packed, specs = pack_columns(cols)
-    width = packed.shape[1]
-
-    rank = dest_ranks(dest, n_shards)
-    ok = (dest < n_shards) & (rank < bucket)
-    slot = jnp.where(ok, dest * bucket + rank, n_shards * bucket)
-    buf = jnp.zeros((n_shards * bucket, width), jnp.uint32
-                    ).at[slot].set(packed, mode="drop")
-
+    width = packed.shape[0]
     sent = jnp.minimum(hist, bucket)
     overflow = jnp.sum(hist - sent)
 
+    # frame per destination: ``bucket`` data rows (+ the metadata row when
+    # the frame travels), all written by one scatter into one buffer
+    frame = bucket + (axis is not None)
+    rank = dest_ranks(dest, n_shards)
+    ok = (dest < n_shards) & (rank < bucket)
+    slot = jnp.where(ok, dest * frame + rank, n_shards * frame)
+    buf = jnp.zeros((width, n_shards * frame), jnp.uint32
+                    ).at[:, slot].set(packed, mode="drop")
+
     if axis is not None:
-        meta = jnp.zeros((n_shards, 1, width), jnp.uint32
-                         ).at[:, 0, 0].set(sent.astype(jnp.uint32))
-        framed = jnp.concatenate(
-            [buf.reshape(n_shards, bucket, width), meta], axis=1)
-        recv = spmd_alltoall(framed.reshape(-1, width), axis)
-        recv = recv.reshape(n_shards, bucket + 1, width)
-        recv_cnt = recv[:, bucket, 0].astype(jnp.int32)
-        buf = recv[:, :bucket].reshape(n_shards * bucket, width)
+        meta = jnp.arange(n_shards, dtype=jnp.int32) * frame + bucket
+        buf = buf.at[0, meta].set(sent.astype(jnp.uint32))
+        buf = spmd_alltoall(buf, axis, split_axis=1, concat_axis=1)
+        recv_cnt = buf[0, meta].astype(jnp.int32)
     else:
         recv_cnt = sent
 
-    pos = jnp.arange(n_shards * bucket, dtype=jnp.int32)
-    valid = (pos % bucket) < recv_cnt[pos // bucket]
+    # metadata rows sit at offset ``bucket`` of their frame: never valid
+    pos = jnp.arange(n_shards * frame, dtype=jnp.int32)
+    valid = (pos % frame) < recv_cnt[pos // frame]
     return unpack_columns(buf, specs), valid, overflow
 
 

@@ -283,26 +283,29 @@ class DistTable:
     @classmethod
     def from_local(cls, table: Table, ctx: HPTMTContext,
                    capacity: Optional[int] = None) -> "DistTable":
-        """Block-partition a local table's valid rows across shards."""
+        """Block-partition a local table's valid rows across shards.
+
+        On a mesh the blocks are cut on the host and each goes straight
+        to its own device (:meth:`_placed`), so no device ever holds the
+        whole table."""
         p = ctx.n_shards
-        n = table.num_rows
+        n = int(table.num_rows)
         per = (n + p - 1) // p  # rows per shard (last may be short)
         cap = capacity or -(-table.capacity // p)
         # row r goes to shard r // per at slot r % per
-        idx = jnp.arange(p * cap, dtype=jnp.int32)
+        idx = np.arange(p * cap, dtype=np.int64)
         shard, slot = idx // cap, idx % cap
         src = shard * per + slot
         valid = (slot < per) & (src < n)
-        src = jnp.where(valid, src, 0)
-        cols = {k: jnp.where(
-            valid.reshape((-1,) + (1,) * (v.ndim - 1)), v[src],
-            jnp.zeros_like(v[src])) for k, v in table.columns.items()}
-        counts = jnp.clip(n - jnp.arange(p, dtype=jnp.int32) * per, 0, per)
-        counts = jnp.minimum(counts, cap).astype(jnp.int32)
-        dt = cls(cols, counts)
-        if ctx.mesh is not None:
-            dt = dt.with_sharding(ctx)
-        return dt
+        src = np.where(valid, src, 0)
+        cols = {}
+        for k, v in table.columns.items():
+            h = np.asarray(v)[src]
+            h[~valid] = 0
+            cols[k] = h
+        counts = np.clip(n - np.arange(p) * per, 0, per)
+        counts = np.minimum(counts, cap).astype(np.int32)
+        return cls._placed(cols, counts, ctx)
 
     @classmethod
     def from_shard_tables(cls, tables: Sequence[Table], ctx: HPTMTContext,
@@ -313,7 +316,9 @@ class DistTable:
         ``i``'s block (padded to the common capacity).  Used by the storage
         scan to place on-disk shard files back onto their shards —
         ``partitioning`` is attached verbatim, so callers assert the layout
-        evidence truthfully (DESIGN.md §4/§5).
+        evidence truthfully (DESIGN.md §4/§5).  Columns may be host
+        (numpy) arrays; the blocks are padded and joined on the host and
+        each is placed on its own device.
         """
         if len(tables) != ctx.n_shards:
             raise ValueError(f"{len(tables)} shard tables for a "
@@ -324,14 +329,29 @@ class DistTable:
                 raise ValueError(f"shard {i} columns {t.column_names} != "
                                  f"shard 0 columns {names}")
         cap = max(t.capacity for t in tables)
-        cols = {k: jnp.concatenate([_pad_axis0(t.columns[k], cap)
-                                    for t in tables], axis=0)
+
+        def pad(x):
+            x = np.asarray(x)
+            return np.pad(x, [(0, cap - x.shape[0])]
+                          + [(0, 0)] * (x.ndim - 1))
+
+        cols = {k: np.concatenate([pad(t.columns[k]) for t in tables])
                 for k in names}
-        counts = jnp.stack([jnp.minimum(t.num_rows, cap) for t in tables])
-        dt = cls(cols, counts, partitioning)
-        if ctx.mesh is not None:
-            dt = dt.with_sharding(ctx)
-        return dt
+        counts = np.array([min(int(t.num_rows), cap) for t in tables],
+                          np.int32)
+        return cls._placed(cols, counts, ctx, partitioning)
+
+    @classmethod
+    def _placed(cls, cols: Dict[str, np.ndarray], counts: np.ndarray,
+                ctx: HPTMTContext, partitioning: Partitioning = None
+                ) -> "DistTable":
+        """Host columns → device arrays, each row block on its own device."""
+        if ctx.mesh is None:
+            return cls({k: jnp.asarray(v) for k, v in cols.items()},
+                       jnp.asarray(counts), partitioning)
+        return cls({k: jax.device_put(v, ctx.row_sharding(v.ndim))
+                    for k, v in cols.items()},
+                   jax.device_put(counts, ctx.row_sharding(1)), partitioning)
 
     def with_sharding(self, ctx: HPTMTContext) -> "DistTable":
         if ctx.mesh is None:
@@ -349,19 +369,21 @@ class DistTable:
 
     def to_local(self) -> Table:
         """Gather all shards into one compacted local table."""
-        tables = [self.shard_table(i) for i in range(self.n_shards)]
-        total_cap = self.capacity * self.n_shards
-        out_cols = {}
-        # concatenate valid prefixes
-        for name in self.column_names:
-            pieces = [np.asarray(t.columns[name][:int(t.num_rows)])
-                      for t in tables]
-            arr = np.concatenate(pieces, axis=0) if pieces else np.zeros((0,))
-            out_cols[name] = arr
-        n = sum(int(t.num_rows) for t in tables)
+        cols = self.to_numpy()
+        n = int(np.asarray(self.counts).sum())
         return Table.from_arrays(
-            {k: jnp.asarray(v) for k, v in out_cols.items()},
-            num_rows=n, capacity=total_cap)
+            {k: jnp.asarray(v) for k, v in cols.items()},
+            num_rows=n, capacity=self.capacity * self.n_shards)
 
     def to_numpy(self) -> Dict[str, np.ndarray]:
-        return self.to_local().to_numpy()
+        """Valid rows of every shard, in shard order, on the host.  Each
+        device's block is copied straight to host memory: no device
+        gathers the table on the way."""
+        c = self.capacity
+        counts = np.asarray(self.counts)
+        out = {}
+        for name in self.column_names:
+            host = np.asarray(self.columns[name])
+            out[name] = np.concatenate(
+                [host[i * c:i * c + int(k)] for i, k in enumerate(counts)])
+        return out

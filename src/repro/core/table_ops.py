@@ -1013,24 +1013,24 @@ def _segment_aggregates(cols: Cols, aggs, seg_id, n_segments: int):
         c for c, a in aggs if a in ("sum", "mean")))
     parts, spans = [], []  # spans: (col name | None=count, trailing, lanes)
     if need_count:
-        parts.append(jnp.ones((cap, 1), jnp.float32))
+        parts.append(jnp.ones((1, cap), jnp.float32))
         spans.append((None, (), 1))
     for c in sum_cols:
-        v = cols[c].astype(jnp.float32).reshape(cap, -1)
+        v = cols[c].astype(jnp.float32).reshape(cap, -1).T   # lanes-major
         parts.append(v)
-        spans.append((c, tuple(cols[c].shape[1:]), v.shape[1]))
+        spans.append((c, tuple(cols[c].shape[1:]), v.shape[0]))
     seg_count, sums = None, {}
     if parts:
         fused = segops.segment_reduce_fused(
-            jnp.concatenate(parts, axis=1), seg_id, n_segments)
+            jnp.concatenate(parts, axis=0), seg_id, n_segments)
         off = 0
         for name, trailing, lanes in spans:
-            block = fused[:, off:off + lanes]
+            block = fused[off:off + lanes]
             off += lanes
             if name is None:
-                seg_count = block[:, 0]
+                seg_count = block[0]
             else:
-                sums[name] = block.reshape((fused.shape[0],) + trailing)
+                sums[name] = block.T.reshape((fused.shape[1],) + trailing)
     minmax = {}
     for col, agg in aggs:
         if agg in ("min", "max") and (col, agg) not in minmax:

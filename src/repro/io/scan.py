@@ -125,6 +125,7 @@ class ScanStats:
     columns_read: int = 0
     rows_on_disk: int = 0      # dataset total per metadata
     rows_scanned: int = 0      # materialized from surviving fragments
+    bytes_read: int = 0        # bytes of the columns read, as decoded
     rows_selected: int = 0     # after the residual predicate
     rows_overflowed: int = 0   # dropped by the §2 capacity contract
     fragments_quarantined: int = 0  # corrupt fragments skipped (opt-in)
@@ -354,6 +355,7 @@ class ScanSource:
                         for c in self.read_columns}
                 n = 0
             self.stats.rows_scanned += n
+            self.stats.bytes_read += sum(v.nbytes for v in cols.values())
             sp.attrs["rows_scanned"] = n
             if self.predicate:
                 keep = np.ones(n, bool)
@@ -397,10 +399,9 @@ class ScanSource:
             cols = {k: v[:capacity] for k, v in cols.items()}
             n = capacity
             self.stats.rows_overflowed += overflow
-        jcols = {k: _to_jax_column(k, v, self.allow_narrowing)
+        hcols = {k: _host_column(k, v, self.allow_narrowing, capacity)
                  for k, v in cols.items()}
-        return Table.from_arrays(jcols, num_rows=n, capacity=capacity), \
-            overflow
+        return Table(hcols, n), overflow
 
     def to_dist_table(self) -> Tuple[DistTable, int]:
         """Materialize the whole scan → ``(DistTable, overflow)``."""
@@ -449,11 +450,10 @@ class ScanSource:
             tables = []
             for f in frags:
                 if f is None:
-                    cols, n = self._empty_shard()
-                    jcols = {k: _to_jax_column(k, v, self.allow_narrowing)
-                             for k, v in cols.items()}
-                    tables.append(Table.from_arrays(jcols, num_rows=0,
-                                                    capacity=cap))
+                    cols, _ = self._empty_shard()
+                    tables.append(Table(
+                        {k: _host_column(k, v, self.allow_narrowing, cap)
+                         for k, v in cols.items()}, 0))
                 else:
                     t, _ = self._shard_table([f], cap)
                     tables.append(t)
@@ -487,8 +487,11 @@ def read_dataset(path: str, *, ctx, columns: Optional[Sequence[str]] = None,
 _NARROW = {"int64": np.int32, "uint64": np.uint32, "float64": np.float32}
 
 
-def _to_jax_column(name: str, arr: np.ndarray, allow_narrowing: bool):
-    """Move a host column into jax, refusing silent 64→32-bit data loss.
+def _host_column(name: str, arr: np.ndarray, allow_narrowing: bool,
+                 capacity: int) -> np.ndarray:
+    """A host column as jax will hold it, padded to ``capacity`` rows,
+    refusing silent 64→32-bit data loss.  It stays on the host until
+    :meth:`DistTable.from_shard_tables` places each shard on its device.
 
     With jax x64 disabled (the default), ``jnp.asarray`` would silently
     narrow 64-bit columns.  We narrow explicitly and — unless
@@ -497,7 +500,6 @@ def _to_jax_column(name: str, arr: np.ndarray, allow_narrowing: bool):
     silently, DESIGN.md §2/§5).
     """
     import jax
-    import jax.numpy as jnp
 
     if arr.dtype.name in _NARROW and not jax.config.jax_enable_x64:
         cast = arr.astype(_NARROW[arr.dtype.name])
@@ -513,4 +515,5 @@ def _to_jax_column(name: str, arr: np.ndarray, allow_narrowing: bool):
                     f"is disabled — enable jax_enable_x64, cast the data, "
                     f"or pass allow_narrowing=True to accept the loss")
         arr = cast
-    return jnp.asarray(arr)
+    return np.pad(arr, [(0, capacity - arr.shape[0])]
+                  + [(0, 0)] * (arr.ndim - 1))

@@ -4,7 +4,8 @@ Online-softmax tiling (Flash-Attention 2 schedule) adapted to the TPU memory
 hierarchy: q/k/v tiles stream HBM→VMEM under BlockSpec control; the two
 matmuls per tile run on the MXU with fp32 accumulation; running max / sum /
 accumulator live in VMEM scratch that persists across the (innermost)
-key-block grid dimension.
+key-block grid dimension; the running max and sum are ``(block_q, 1)``
+columns, so every vector in the kernel is 2-D.
 
 Layout: heads are folded into the leading grid axis.  GQA never
 materializes repeated KV heads — the kv BlockSpec index-maps query head
@@ -79,26 +80,35 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             allow &= (rows - cols) < window
         s = jnp.where(allow, s, _NEG_INF)
 
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                       # (bq, 1)
         l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(allow, p, 0.0)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_new
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+        acc_ref[...] = acc_ref[...] * alpha + pv
 
     @pl.when(kj == n_kblocks - 1)
     def _finish():
-        l = l_ref[...]
-        out = acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where((l > 0)[:, None], out, 0.0)
+        l = l_ref[...]                            # (bq, 1)
+        out = acc_ref[...] / jnp.maximum(l, 1e-30)
+        out = jnp.where(l > 0, out, 0.0)
         o_ref[0] = out.astype(o_ref.dtype)
+
+
+def vmem_bytes(d: int, block_q: int = 128, block_k: int = 128) -> int:
+    """VMEM the kernel holds: double-buffered q/k/v/o blocks, the f32
+    accumulator and running columns, and the f32 score tiles."""
+    lanes = -(-d // 128) * 128
+    io = 2 * 4 * lanes * (2 * block_q + 2 * block_k)
+    scratch = 4 * (block_q * lanes + 2 * block_q * 128)
+    return io + scratch + 3 * 4 * block_q * block_k
 
 
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -146,8 +156,8 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_pad, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)

@@ -1,11 +1,10 @@
 """Public entry points for the hash-join build/probe engine.
 
-Dispatch mirrors ``hash_partition/ops.py``: the probe-count hot loop runs
-the compiled Pallas kernel on TPU (within its VMEM table budget) and the
-pure-jnp reference elsewhere; ``force`` overrides for testing ("pallas"
-uses interpret mode off-TPU).  Build (contended scatter-min) and emit
-(binary search + gather walk) lower well through XLA everywhere — they
-have no Pallas variant and always take the reference path.
+All four primitives run the pure-jnp reference everywhere (DESIGN.md §7):
+build (contended scatter-min), the probe walk and emit (binary search +
+gather walk) lower through XLA.  The probe's random walk over a slot table
+has no Pallas form on a TPU — Mosaic gathers only within a vreg — so there
+is no probe kernel to dispatch to.
 
 These primitives serve three operators (DESIGN.md §8): join
 (``build_table`` + two-pass probe), set-op membership/dedup and the
@@ -13,39 +12,10 @@ groupby hash kernel (``build_table_unique``).
 """
 from __future__ import annotations
 
-import jax
-
-from . import kernel as _kernel
 from . import ref as _ref
 
 build_table = _ref.build_table
 build_table_unique = _ref.build_table_unique
 slot_payload = _ref.slot_payload
 emit_lookup = _ref.emit_lookup
-
-#: Largest slot-table footprint (uint32 lanes) the Pallas probe kernel may
-#: keep VMEM-resident; bigger tables fall back to the jnp reference.
-_PALLAS_MAX_TABLE_LANES = 1 << 21
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def probe(table_row, slot_h2, slot_keys, ph1, ph2, pkeys_u32, pvalid,
-          max_matches: int = 1, max_probes: int = 64,
-          force: str | None = None):
-    """Fused probe: match counts, first-match registers, exhausted flags.
-
-    Pallas on TPU when the slot table fits VMEM, jnp oracle elsewhere.
-    Returns ``(cnt (N,) int32, rimat (N, max_matches) int32,
-    exhausted (N,) bool)``.
-    """
-    table_lanes = table_row.shape[0] * (2 + slot_keys.shape[1])
-    if force == "pallas" or (force is None and _on_tpu()
-                             and table_lanes <= _PALLAS_MAX_TABLE_LANES):
-        return _kernel.probe_pallas(
-            table_row, slot_h2, slot_keys, ph1, ph2, pkeys_u32, pvalid,
-            max_matches, max_probes, interpret=not _on_tpu())
-    return _ref.probe(table_row, slot_h2, slot_keys, ph1, ph2, pkeys_u32,
-                      pvalid, max_matches, max_probes)
+probe = _ref.probe

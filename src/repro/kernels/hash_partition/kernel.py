@@ -49,11 +49,11 @@ def _kernel(keys_ref, valid_ref, *out_refs, n_parts: int, sentinel: int,
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    block_n = dest_ref.shape[0]
-    h1 = jnp.full((block_n,), _H1_INIT, jnp.uint32)
-    h2 = jnp.full((block_n,), _H2_INIT, jnp.uint32)
+    shape = dest_ref.shape                   # (1, block_n): rows on lanes
+    h1 = jnp.full(shape, _H1_INIT, jnp.uint32)
+    h2 = jnp.full(shape, _H2_INIT, jnp.uint32)
     for c in range(n_cols):
-        k = keys_ref[:, c]
+        k = keys_ref[pl.ds(c, 1), :]
         h1 = _mix(h1, k, _MUL1)
         if with_hashes:
             h2 = _mix(h2, k ^ _K2_XOR, _MUL2)
@@ -67,48 +67,54 @@ def _kernel(keys_ref, valid_ref, *out_refs, n_parts: int, sentinel: int,
         h2_ref[...] = h2 ^ (h2 >> 16)
 
     p_pad = hist_ref.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (p_pad, block_n), 0)
-    onehot = rows == dest[None, :]
-    hist_ref[...] += jnp.sum(onehot.astype(jnp.int32), axis=1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (p_pad, shape[1]), 0)
+    onehot = rows == dest
+    hist_ref[...] += jnp.sum(onehot.astype(jnp.int32), axis=1,
+                             keepdims=True)
+
+
+def vmem_bytes(n_cols: int, n_parts: int, block_n: int = 1024) -> int:
+    """VMEM the kernel holds: double-buffered key/valid/output blocks plus
+    the ``(parts, block_n)`` one-hot histogram tile."""
+    p_pad = max(8, -(-n_parts // 128) * 128)
+    io = 2 * 4 * block_n * (-(-n_cols // 8) * 8 + 4 * 8) + 4 * p_pad * 128
+    return io + 2 * 4 * p_pad * block_n
 
 
 def hash_partition_pallas(keys_u32: jnp.ndarray, valid: jnp.ndarray,
                           n_parts: int, *, block_n: int = 1024,
                           interpret: bool = False,
                           return_hashes: bool = False):
-    """keys_u32 (N, K) uint32, valid (N,) int32 → (dest (N,), hist (P,))
-    plus ``(h1 (N,), h2 (N,))`` uint32 when ``return_hashes``."""
-    n, k = keys_u32.shape
+    """keys_u32 (K, N) uint32 (one row per key column), valid (N,) →
+    (dest (N,), hist (P,)) plus ``(h1 (N,), h2 (N,))`` uint32 when
+    ``return_hashes``.  Rows lie along the lane axis of every block."""
+    k, n = keys_u32.shape
     n_pad = -(-n // block_n) * block_n
     p_pad = max(8, -(-n_parts // 128) * 128)
-    keys = jnp.pad(keys_u32, ((0, n_pad - n), (0, 0)))
-    val = jnp.pad(valid.astype(jnp.int32), (0, n_pad - n))
+    keys = jnp.pad(keys_u32, ((0, 0), (0, n_pad - n)))
+    val = jnp.pad(valid.astype(jnp.int32), (0, n_pad - n))[None, :]
 
-    row_spec = pl.BlockSpec((block_n,), lambda i: (i,))
-    row_shape = jax.ShapeDtypeStruct((n_pad,), jnp.int32)
+    row_spec = pl.BlockSpec((1, block_n), lambda i: (0, i))
     out_specs = [row_spec]
-    out_shape = [row_shape]
+    out_shape = [jax.ShapeDtypeStruct((1, n_pad), jnp.int32)]
     if return_hashes:
         out_specs += [row_spec, row_spec]
-        out_shape += [jax.ShapeDtypeStruct((n_pad,), jnp.uint32)] * 2
-    out_specs.append(pl.BlockSpec((p_pad,), lambda i: (0,)))
-    out_shape.append(jax.ShapeDtypeStruct((p_pad,), jnp.int32))
+        out_shape += [jax.ShapeDtypeStruct((1, n_pad), jnp.uint32)] * 2
+    out_specs.append(pl.BlockSpec((p_pad, 1), lambda i: (0, 0)))
+    out_shape.append(jax.ShapeDtypeStruct((p_pad, 1), jnp.int32))
 
     outs = pl.pallas_call(
         functools.partial(_kernel, n_parts=n_parts, sentinel=p_pad,
                           n_cols=k, with_hashes=return_hashes),
         grid=(n_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n, k), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
+        in_specs=[pl.BlockSpec((k, block_n), lambda i: (0, i)), row_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
     )(keys, val)
-    dest, hist = outs[0], outs[-1]
+    dest, hist = outs[0][0, :n], outs[-1][:n_parts, 0]
     # sentinel rows → n_parts (match ref convention)
-    d = jnp.where(dest[:n] == p_pad, n_parts, dest[:n])
+    d = jnp.where(dest == p_pad, n_parts, dest)
     if return_hashes:
-        return d, hist[:n_parts], outs[1][:n], outs[2][:n]
-    return d, hist[:n_parts]
+        return d, hist, outs[1][0, :n], outs[2][0, :n]
+    return d, hist

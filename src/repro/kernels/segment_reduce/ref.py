@@ -25,11 +25,13 @@ def segment_reduce(values: jnp.ndarray, segment_ids: jnp.ndarray,
 
 def segment_reduce_fused(values: jnp.ndarray, segment_ids: jnp.ndarray,
                          num_segments: int) -> jnp.ndarray:
-    """Sum-reduce ``(N, L)`` values by segment in ONE scatter.
+    """Sum-reduce lanes-major ``(L, N)`` values by segment → ``(L, S)``.
 
-    XLA lowers a leading-axis ``segment_sum`` over a 2-D operand to a single
-    scatter-add whose cost is dominated by the row count, not the lane
-    count — measurably cheaper than one scatter per aggregate column
-    (the GroupBy map-side-combine hot loop, DESIGN.md §4).
+    One 1-D scatter-add per lane (the GroupBy map-side-combine hot loop,
+    DESIGN.md §4).  Rows stay on the last axis throughout: a TPU tiles
+    that axis by 128, so an ``(N, L)`` operand or update block with a
+    handful of lanes — what one batched scatter over all lanes lowers
+    to — would occupy ~128/L times its bytes.
     """
-    return jax.ops.segment_sum(values, segment_ids, num_segments)
+    return jnp.stack([jax.ops.segment_sum(v, segment_ids, num_segments)
+                      for v in values])

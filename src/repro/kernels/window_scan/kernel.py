@@ -1,30 +1,21 @@
-"""Pallas TPU kernel for the blocked segmented windowed scan (DESIGN.md §9).
+"""Pallas TPU kernel for the segmented windowed scan (DESIGN.md §9).
 
-Grid layout: the rows are tiled into blocks of ``bc`` window-sized chunks
-(``block_n = bc * window`` rows).  Each grid step loads its own block plus
-the PREVIOUS block (two BlockSpecs over the same operand, the second with a
-clamped ``i-1`` index map) — the only cross-block dependence of the
-two-scan window decomposition is the suffix of the chunk immediately before
-a row's chunk, and with window-aligned blocks that chunk is either inside
-the current block or the last chunk of the previous one.  So one grid step
-computes:
+Layout: rows lie along the 128-wide lane axis — the values enter as an
+``(L, n)`` array and segment starts as a ``(1, n)`` row — so every block
+is a 2-D tile aligned to the (8, 128) tiling.  The grid walks blocks of
+``block_n`` rows.  Each grid step loads its own block plus the PREVIOUS
+block (two BlockSpecs over the same operand, the second with a clamped
+``i-1`` index map): a window reaches at most ``window - 1`` rows back, and
+``block_n >= window - 1``, so the previous block is the whole halo.
 
-  * the segmented prefix scan of its ``bc`` chunks,
-  * the segmented suffix scan of the ``bc`` chunks shifted one to the left
-    (previous block's last chunk + own chunks 0..bc-2),
-  * the per-row combine ``prefix ⊕ suffix[window start]`` via one VMEM
-    gather —
-
-all with the SAME ``_chunk_scan`` helper as the jnp reference, so
-interpret-mode output is bit-identical to ``ref.windowed_scan`` (the oracle
-IS the semantics, as with every kernel in this tree).  There is no
-revisiting of output blocks and no scratch: the kernel is one read of two
-input blocks and one write.
-
-VMEM: 2 value blocks + suffix source + prefix ≈ ``4 * block_n * lanes *
-4B`` — at the default ~512-row blocks this is KBs, far under budget; wide
-windows raise ``block_n`` to one chunk (``bc = 1``), which ``ops.py`` caps
-before dispatching here.
+The step runs the reference's :func:`~.ref._rolling` helper over the
+``(L, 2 * block_n)`` halo+block concatenation, with a lane rotate
+(``pltpu.roll``) as the row shift, and writes the block's half.  A row's
+result only reads rows back to ``i - window + 1``, which lie inside the
+concatenation, so the wrapped-around lanes of the rotate are never
+selected; the result is bit-identical to ``ref.windowed_scan`` (the oracle
+IS the semantics, as with every kernel in this tree).  No gather, no
+reversal, no scratch, no revisiting of output blocks.
 """
 from __future__ import annotations
 
@@ -33,43 +24,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .ref import _IDENTITY, _chunk_scan, _chunk_suffix, _combine
+from .ref import _IDENTITY, _rolling
 
 
 def _kernel(vals_ref, pvals_ref, seg_ref, pseg_ref, out_ref, *, w: int,
-            bc: int, op: str, block_n: int):
-    pid = pl.program_id(0)
-    base = pid * block_n
-    cur = vals_ref[...]                      # (block_n, L)
-    prev = pvals_ref[...]                    # previous block (clamped at 0)
-    segs = seg_ref[...]                      # (block_n,) i32
-    psegs = pseg_ref[...]
-    lanes = cur.shape[1]
+            op: str, block_n: int):
+    base = pl.program_id(0) * block_n
+    v = jnp.concatenate([pvals_ref[...], vals_ref[...]], axis=1)
+    start = jnp.concatenate([pseg_ref[...], seg_ref[...]], axis=1)
+    # absolute row index of every lane; the halo of block 0 gets negative
+    # indices, below every segment start, so it is never selected
+    idx = base - block_n + jax.lax.broadcasted_iota(
+        jnp.int32, start.shape, 1)
 
-    idx = base + jax.lax.broadcasted_iota(jnp.int32, (block_n, 1), 0)[:, 0]
-    f_cur = segs == idx
-    f_prev = psegs == (idx - block_n)        # garbage at pid=0: never used
+    def shift(x, d):
+        return pltpu.roll(x, d, 1)
 
-    v3 = cur.reshape(bc, w, lanes)
-    f3 = f_cur.reshape(bc, w)
-    prefix = _chunk_scan(v3, f3, op).reshape(block_n, lanes)
+    out = _rolling(v, start, idx, w, op, shift)
+    out_ref[...] = out[:, block_n:]
 
-    # suffix of each row's PREVIOUS chunk: previous block's last chunk
-    # followed by this block's chunks 0..bc-2
-    sv = jnp.concatenate([prev[block_n - w:], cur[:block_n - w]], axis=0)
-    sf = jnp.concatenate([f_prev[block_n - w:], f_cur[:block_n - w]], axis=0)
-    suffix = _chunk_suffix(sv.reshape(bc, w, lanes),
-                           sf.reshape(bc, w), op).reshape(block_n, lanes)
 
-    a = jnp.maximum(idx - (w - 1), segs)
-    chunk_start = (idx // w) * w
-    use_prev = a < chunk_start
-    local_chunk = (idx - base) // w
-    spos = local_chunk * w + (a % w)         # a lives in chunk-1 ⇒ its
-    sval = jnp.take(suffix, spos, axis=0)    # offset there is a mod w
-    out_ref[...] = jnp.where(use_prev[:, None],
-                             _combine(op, sval, prefix), prefix)
+def block_rows(window: int, target_block: int = 512) -> int:
+    """Rows per block: lane-aligned and at least the halo ``window - 1``."""
+    return max(target_block, -(-(window - 1) // 128) * 128)
+
+
+def vmem_bytes(lanes: int, window: int) -> int:
+    """VMEM the kernel holds: double-buffered value/start/output blocks
+    plus about eight ``(lanes, 2 * block_n)`` temporaries."""
+    bn = block_rows(window)
+    sub = -(-lanes // 8) * 8
+    io = 2 * 4 * bn * (2 * sub + 2 * 8 + sub)
+    return io + 8 * 4 * sub * 2 * bn
 
 
 def windowed_scan_pallas(values: jnp.ndarray, seg_start: jnp.ndarray,
@@ -79,27 +67,29 @@ def windowed_scan_pallas(values: jnp.ndarray, seg_start: jnp.ndarray,
     """values (n, L) f32, seg_start (n,) i32 → (n, L); see ref.windowed_scan."""
     n, lanes = values.shape
     w = int(window)
-    bc = max(1, target_block // w)
-    block_n = bc * w
+    block_n = block_rows(w, target_block)
     n_pad = -(-n // block_n) * block_n
-    ident = _IDENTITY[op]
     vals = jnp.pad(values.astype(jnp.float32), ((0, n_pad - n), (0, 0)),
-                   constant_values=ident)
-    idx = jnp.arange(n_pad, dtype=jnp.int32)
-    segs = jnp.concatenate([seg_start.astype(jnp.int32), idx[n:]]) \
-        if n_pad > n else seg_start.astype(jnp.int32)
+                   constant_values=_IDENTITY[op]).T
+    # padding rows are their own segments: they never contaminate a window
+    segs = jnp.concatenate([seg_start.astype(jnp.int32),
+                            jnp.arange(n, n_pad, dtype=jnp.int32)])[None, :]
 
-    row_spec = pl.BlockSpec((block_n, lanes), lambda i: (i, 0))
-    prev_spec = pl.BlockSpec((block_n, lanes),
-                             lambda i: (jnp.maximum(i - 1, 0), 0))
-    seg_spec = pl.BlockSpec((block_n,), lambda i: (i,))
-    pseg_spec = pl.BlockSpec((block_n,), lambda i: (jnp.maximum(i - 1, 0),))
+    def cur(i):
+        return (0, i)
+
+    def prev(i):
+        return (0, jnp.maximum(i - 1, 0))
+
     out = pl.pallas_call(
-        functools.partial(_kernel, w=w, bc=bc, op=op, block_n=block_n),
+        functools.partial(_kernel, w=w, op=op, block_n=block_n),
         grid=(n_pad // block_n,),
-        in_specs=[row_spec, prev_spec, seg_spec, pseg_spec],
-        out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct((n_pad, lanes), jnp.float32),
+        in_specs=[pl.BlockSpec((lanes, block_n), cur),
+                  pl.BlockSpec((lanes, block_n), prev),
+                  pl.BlockSpec((1, block_n), cur),
+                  pl.BlockSpec((1, block_n), prev)],
+        out_specs=pl.BlockSpec((lanes, block_n), cur),
+        out_shape=jax.ShapeDtypeStruct((lanes, n_pad), jnp.float32),
         interpret=interpret,
     )(vals, vals, segs, segs)
-    return out[:n]
+    return out[:, :n].T

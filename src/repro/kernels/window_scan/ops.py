@@ -1,10 +1,10 @@
 """Public entry points for the windowed-scan engine (DESIGN.md §9).
 
-Dispatch mirrors ``segment_reduce/ops.py``: the compiled Pallas kernel on
-TPU, the pure-jnp reference elsewhere (itself fast XLA code);
-``force="pallas"`` runs the kernel in interpret mode for testing and must
-match the reference bit-for-bit (shared chunk-scan helper).  The expanding
-(cumulative) scan has no Pallas variant — it is one chunk-sized ladder of
+Dispatch (``kernels/dispatch.py``): the compiled Pallas kernel on TPU when
+its blocks fit the scoped-VMEM limit, the pure-jnp reference elsewhere
+(itself fast XLA code).  Interpret-mode kernel output must match the
+reference bit-for-bit (shared rolling helper).  The expanding
+(cumulative) scan has no Pallas variant — it is one ladder of
 shift-combines with nothing extra for a kernel to fuse — and always takes
 the reference path.
 
@@ -20,27 +20,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
+
 from . import kernel as _kernel
 from . import ref as _ref
 
 segmented_cumulative = _ref.segmented_cumulative
 
-#: Windows wider than this skip the Pallas kernel: a block is at least one
-#: window-sized chunk, and a multi-thousand-row chunk ladder stops fitting
-#: comfortably in VMEM next to its halo block.
-_PALLAS_MAX_WINDOW = 4096
-
 _OPS = ("sum", "min", "max")
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3, 4),
-                   static_argnames=("op", "force"))
+@functools.partial(jax.jit, static_argnums=(2, 3), static_argnames=("op",))
 def windowed_scan(values: jnp.ndarray, seg_start: jnp.ndarray, window: int,
-                  op: str = "sum", force: str | None = None) -> jnp.ndarray:
+                  op: str = "sum") -> jnp.ndarray:
     """Rolling segment-clipped reduction; see ``ref.windowed_scan``.
 
     ``out[i] = op(values[max(i - window + 1, seg_start[i]) .. i])``.
@@ -51,10 +43,11 @@ def windowed_scan(values: jnp.ndarray, seg_start: jnp.ndarray, window: int,
     squeeze = values.ndim == 1
     v = values[:, None] if squeeze else values
     v = v.astype(jnp.float32)
-    if force == "pallas" or (force is None and _on_tpu()
-                             and window <= _PALLAS_MAX_WINDOW):
+    impl = dispatch.choose("window_scan",
+                           vmem_bytes=_kernel.vmem_bytes(v.shape[1], window))
+    if impl != "xla":
         out = _kernel.windowed_scan_pallas(v, seg_start, window, op,
-                                           interpret=not _on_tpu())
+                                           interpret=impl == "interpret")
     else:
         out = _ref.windowed_scan(v, seg_start, window, op)
     return out[:, 0] if squeeze else out

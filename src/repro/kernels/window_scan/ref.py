@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the blocked segmented windowed scan (DESIGN.md §9).
+"""Pure-jnp oracle for the segmented windowed scan (DESIGN.md §9).
 
 The windowed-aggregation hot loop: for every row ``i`` of a table sorted by
 ``(partition, order)`` keys, reduce the rows of the same partition inside a
@@ -12,35 +12,30 @@ values stacked as ``(n, L)`` lanes, exactly like ``segment_reduce_fused``.
 segments are contiguous because the table is sorted, so no per-row hash or
 grouping structure is needed.
 
-The algorithm is the classic two-scan sliding-window decomposition, made
-segment-aware:
+The algorithm is segment-clipped doubling (:func:`_rolling`):
 
-  1. rows are split into chunks of exactly ``window`` rows;
-  2. a *segmented* inclusive prefix scan runs forward within each chunk and
-     a segmented suffix scan runs backward (both reset at segment starts —
-     :func:`_chunk_scan`, a Hillis–Steele ladder of ``log2(window)``
-     shift-combine steps);
-  3. a window ending at ``i`` either lies entirely inside ``i``'s chunk
-     (then the prefix at ``i`` IS the answer: the window start can never
-     precede the chunk start without leaving the chunk, because chunks are
-     window-sized) or it straddles one chunk boundary (then it is the
-     disjoint union of a suffix in the previous chunk and the prefix at
-     ``i`` — one gather + one combine).
+  1. ``P_0 = values``; ``P_{k+1}[j] = P_k[j - 2^k] ⊕ P_k[j]`` when row
+     ``j - 2^k`` is still in ``j``'s segment, else ``P_k[j]`` — so
+     ``P_k[j]`` reduces the last ``2^k`` rows up to ``j``, clipped at the
+     segment start;
+  2. the window is the binary decomposition of ``window``: walking the set
+     bits from low to high, the piece of size ``2^k`` ending ``off`` rows
+     back is ``P_k[i - off]``, kept while ``i - off`` is still in the
+     segment.
 
-Total work is O(n log window) fully-vectorized ops, zero sorts, zero
-scatters.  The Pallas kernel (``kernel.py``) runs the SAME ``_chunk_scan``
-helper on its VMEM blocks, so interpret-mode kernel output is bit-identical
-to this reference — float summation order and all (tested in
-``tests/test_window.py``).
+Total work is O(n log window) shift-and-select steps, zero sorts, zero
+scatters, zero gathers.  The Pallas kernel (``kernel.py``) runs the SAME
+:func:`_rolling` helper on its VMEM blocks with a lane rotate for the
+shift, so interpret-mode kernel output is bit-identical to this reference —
+float summation order and all (tested in ``tests/test_window.py``).
 
-:func:`segmented_cumulative` reuses the scan ladder at chunk size = n for
-expanding (cumulative) aggregates; lag/lead/row_number/rank need no kernel
-at all (they are gathers off the same segment machinery) and live in
-``repro.window``.
+:func:`segmented_cumulative` runs a Hillis–Steele ladder at chunk size = n
+for expanding (cumulative) aggregates; lag/lead/row_number/rank need no
+kernel at all (they are gathers off the same segment machinery) and live
+in ``repro.window``.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 _IDENTITY = {"sum": 0.0, "min": float("inf"), "max": float("-inf")}
@@ -62,8 +57,7 @@ def _chunk_scan(v: jnp.ndarray, f: jnp.ndarray, op: str) -> jnp.ndarray:
     Hillis–Steele ladder: at offset ``d`` a row whose accumulated span is
     still open combines with the row ``d`` to its left and inherits its
     completion flag.  The combine ORDER is fixed (left operand is always
-    the earlier span), so float results are deterministic and shared
-    bit-for-bit with the Pallas kernel, which calls this same helper.
+    the earlier span), so float results are deterministic.
     """
     c = v.shape[1]
     ident = jnp.asarray(_IDENTITY[op], v.dtype)
@@ -79,19 +73,30 @@ def _chunk_scan(v: jnp.ndarray, f: jnp.ndarray, op: str) -> jnp.ndarray:
     return v
 
 
-def _chunk_suffix(v: jnp.ndarray, new_seg: jnp.ndarray,
-                  op: str) -> jnp.ndarray:
-    """Segmented suffix scan along axis 1: ``out[j] = op(v[j .. e])`` where
-    ``e`` is the last row of ``j``'s segment within the chunk.
+def _rolling(v, start, idx, window: int, op: str, shift):
+    """Segment-clipped rolling reduction over the row axis of ``v``.
 
-    Runs :func:`_chunk_scan` on the reversed chunk; the reversed flags mark
-    rows whose successor starts a new segment (= segment ENDS), which are
-    exactly the reversed scan's segment starts.
+    ``start``/``idx`` (broadcastable to ``v``) hold each row's segment
+    start and its own index; ``shift(x, d)`` moves ``x`` ``d`` rows toward
+    higher indices (what fills the first ``d`` rows is never selected:
+    there ``idx - d < start``).  Shared by the reference and the Pallas
+    kernel — the same elementwise steps in the same order.
     """
-    rf = jnp.concatenate(
-        [new_seg[:, 1:], jnp.zeros_like(new_seg[:, :1])], axis=1)
-    out = _chunk_scan(v[:, ::-1], rf[:, ::-1], op)
-    return out[:, ::-1]
+    p, acc, off, k = v, None, 0, 0
+    while (1 << k) <= window:
+        size = 1 << k
+        if window & size:
+            if acc is None:
+                acc = p
+            else:
+                keep = start <= idx - off
+                acc = jnp.where(keep, _combine(op, shift(p, off), acc), acc)
+            off += size
+        if (2 << k) <= window:
+            keep = start <= idx - size
+            p = jnp.where(keep, _combine(op, shift(p, size), p), p)
+        k += 1
+    return acc
 
 
 def windowed_scan(values: jnp.ndarray, seg_start: jnp.ndarray, window: int,
@@ -104,29 +109,17 @@ def windowed_scan(values: jnp.ndarray, seg_start: jnp.ndarray, window: int,
     partition, the SQL ROWS BETWEEN semantics).  ``seg_start[i]`` must
     satisfy ``seg_start[i] <= i`` and be constant within each segment.
     """
-    n, lanes = values.shape
-    w = int(window)
-    n_pad = -(-n // w) * w
+    n = values.shape[0]
     ident = jnp.asarray(_IDENTITY[op], values.dtype)
-    vals = jnp.pad(values, ((0, n_pad - n), (0, 0)), constant_values=ident)
-    idx = jnp.arange(n_pad, dtype=jnp.int32)
-    # padding rows are their own segments: they never contaminate a window
-    segs = jnp.concatenate([seg_start.astype(jnp.int32),
-                            idx[n:]]) if n_pad > n else seg_start
-    new_seg = segs == idx
 
-    m = n_pad // w
-    v3 = vals.reshape(m, w, lanes)
-    f3 = new_seg.reshape(m, w)
-    prefix = _chunk_scan(v3, f3, op).reshape(n_pad, lanes)
-    suffix = _chunk_suffix(v3, f3, op).reshape(n_pad, lanes)
+    def shift(x, d):
+        if d >= n:
+            return jnp.full_like(x, ident)
+        return jnp.concatenate([jnp.full_like(x[:d], ident), x[:n - d]])
 
-    a = jnp.maximum(idx - (w - 1), segs)
-    chunk_start = (idx // w) * w
-    use_prev = a < chunk_start  # window straddles one chunk boundary
-    sval = suffix[jnp.clip(a, 0, n_pad - 1)]
-    out = jnp.where(use_prev[:, None], _combine(op, sval, prefix), prefix)
-    return out[:n]
+    idx = jnp.arange(n, dtype=jnp.int32)[:, None]
+    return _rolling(values, seg_start.astype(jnp.int32)[:, None], idx,
+                    int(window), op, shift)
 
 
 def segmented_cumulative(values: jnp.ndarray, seg_start: jnp.ndarray,
@@ -134,10 +127,10 @@ def segmented_cumulative(values: jnp.ndarray, seg_start: jnp.ndarray,
     """values (n, L), seg_start (n,) → expanding (cumulative) reductions.
 
     ``out[i] = op(values[seg_start[i] .. i])`` — the unbounded-window
-    special case, computed as one chunk-sized segmented scan (the same
-    ladder the windowed scan uses, at chunk size n).  No Pallas variant:
-    the ladder is plain shift-combine XLA code with nothing for a kernel
-    to fuse beyond what the compiler already does.
+    special case, computed as one segmented Hillis–Steele scan over all
+    ``n`` rows.  No Pallas variant: the ladder is plain shift-combine XLA
+    code with nothing for a kernel to fuse beyond what the compiler
+    already does.
     """
     n = values.shape[0]
     f = (seg_start.astype(jnp.int32) == jnp.arange(n, dtype=jnp.int32))

@@ -74,12 +74,13 @@ def measure(arch, shape_name, set_overrides=None, rule_overrides=None,
     coll = extr(meas[1][2].cost_s, meas[2][2].cost_s)
     coll_b = extr(meas[1][2].total_bytes, meas[2][2].total_bytes)
     mf = rl.model_flops_for(cfg, cell)
-    compute_s = flops / rl.PEAK_FLOPS
-    memory_s = byts / rl.HBM_BW
+    chip = rl.peaks(rl.DRYRUN_KIND)
+    compute_s = flops / chip.bf16_flops
+    memory_s = byts / chip.hbm_bw
     step = max(compute_s, memory_s, coll)
     print(f"compute {compute_s:.3f}s | memory {memory_s:.3f}s | "
           f"collective {coll:.3f}s  → step {step:.3f}s  "
-          f"mfu {mf/(step*256*rl.PEAK_FLOPS)*100:.1f}%  "
+          f"mfu {mf/(step*256*chip.bf16_flops)*100:.1f}%  "
           f"useful_frac {mf/(flops*256):.2f}  coll {coll_b/1e9:.0f}GB")
 
     print("top collectives (k=2 variant, per-layer-group ×%d):" % g)
@@ -87,7 +88,7 @@ def measure(arch, shape_name, set_overrides=None, rule_overrides=None,
         print(f"  {b/2**30:8.3f} GiB  {kind:20s} {shape}")
     return {"peak": peak, "compute_s": compute_s, "memory_s": memory_s,
             "collective_s": coll, "step_s": step,
-            "mfu": mf / (step * 256 * rl.PEAK_FLOPS)}
+            "mfu": mf / (step * 256 * chip.bf16_flops)}
 
 
 def main(argv=None):

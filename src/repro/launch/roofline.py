@@ -2,9 +2,13 @@
 
 Three terms per (arch × shape × mesh), in seconds:
 
-    compute    = HLO_FLOPs / (chips × 197 TF bf16)
-    memory     = HLO_bytes / (chips × 819 GB/s HBM)
-    collective = Σ per-op collective cost, ICI-hop-weighted, / 50 GB/s/link
+    compute    = HLO_FLOPs / (chips × peak bf16 FLOP/s)
+    memory     = HLO_bytes / (chips × peak HBM bytes/s)
+    collective = Σ per-op collective cost, ICI-hop-weighted, / link bytes/s
+
+The peaks are those of :data:`DRYRUN_KIND` in ``core/device.py``: the
+dry-run meshes (``launch/mesh.py``) describe v5e pods.  Code that measures
+on a real device looks up that device's kind there itself.
 
 cost_analysis() supplies FLOPs/bytes; collective bytes are parsed from the
 compiled HLO text (all-gather / all-reduce / reduce-scatter / all-to-all /
@@ -18,10 +22,11 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s/link (per direction)
+from repro.core.device import ChipPeaks, peaks
+
+
+#: The chip the dry-run production meshes describe (256-chip v5e pods).
+DRYRUN_KIND = "TPU v5 lite"
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -63,7 +68,9 @@ class CollectiveStats:
 
 def parse_collectives(hlo_text: str, replica_groups_size: Optional[int] = None
                       ) -> CollectiveStats:
-    """Sum output-shape bytes of every collective op in the HLO."""
+    """Sum output-shape bytes of every collective op in the HLO; the ring
+    cost is priced at the dry-run chip's link bandwidth."""
+    ici_bw = peaks(DRYRUN_KIND).ici_bw
     counts: Dict[str, int] = {}
     bytes_by: Dict[str, int] = {}
     cost = 0.0
@@ -93,16 +100,16 @@ def parse_collectives(hlo_text: str, replica_groups_size: Optional[int] = None
         frac = (g - 1) / g
         if kind == "all-gather":
             # output is the gathered buffer; each link moves (g-1)/g of it
-            cost += b * frac / ICI_BW
+            cost += b * frac / ici_bw
         elif kind == "reduce-scatter":
             # b is the scattered output shard; ring moves (g-1)·b per chip
-            cost += b * (g - 1) / ICI_BW
+            cost += b * (g - 1) / ici_bw
         elif kind == "all-reduce":
-            cost += 2 * b * frac / ICI_BW
+            cost += 2 * b * frac / ici_bw
         elif kind == "all-to-all":
-            cost += b * frac / ICI_BW
+            cost += b * frac / ici_bw
         elif kind == "collective-permute":
-            cost += b / ICI_BW
+            cost += b / ici_bw
     return CollectiveStats(counts, bytes_by, cost)
 
 
@@ -115,12 +122,16 @@ class Roofline:
     model_flops: float = 0.0   # global analytic 6·N·D / 2·N·tok
 
     @property
+    def chip(self) -> ChipPeaks:
+        return peaks(DRYRUN_KIND)
+
+    @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.chip.bf16_flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.chip.hbm_bw
 
     @property
     def collective_s(self) -> float:
@@ -148,7 +159,8 @@ class Roofline:
         """Model-FLOPs utilization at the roofline step time."""
         if not self.model_flops or not self.step_s:
             return float("nan")
-        return self.model_flops / (self.step_s * self.n_chips * PEAK_FLOPS)
+        return self.model_flops / (self.step_s * self.n_chips
+                                   * self.chip.bf16_flops)
 
     def summary(self) -> Dict:
         return {
@@ -241,15 +253,17 @@ def tpu_adjusted_terms(cfg, cell, n_chips: int, measured: "Roofline",
              cell.kind == "train" else 1)
 
     mem_bytes = param_traffic + act + attn + logits
+    chip = measured.chip
+    hbm_bw, peak_flops = chip.hbm_bw, chip.bf16_flops
     return {
-        "memory_s_tpu": mem_bytes / HBM_BW,
+        "memory_s_tpu": mem_bytes / hbm_bw,
         "collective_s_tpu": measured.collective_s / 2,
-        "step_s_tpu": max(measured.compute_s, mem_bytes / HBM_BW,
+        "step_s_tpu": max(measured.compute_s, mem_bytes / hbm_bw,
                           measured.collective_s / 2),
         "mfu_tpu": (measured.model_flops
-                    / (max(measured.compute_s, mem_bytes / HBM_BW,
+                    / (max(measured.compute_s, mem_bytes / hbm_bw,
                            measured.collective_s / 2)
-                       * n_chips * PEAK_FLOPS)
+                       * n_chips * peak_flops)
                     if measured.model_flops else float("nan")),
     }
 

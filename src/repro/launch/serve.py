@@ -24,6 +24,9 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs import get_config, reduced_config
     from repro.models import transformer as T
     from repro.serve.engine import Engine, ServeConfig

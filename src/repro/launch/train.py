@@ -32,6 +32,9 @@ def main(argv=None):
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs import get_config, reduced_config
     from repro.core import HPTMTContext
     from repro.core.context import make_mesh

@@ -142,14 +142,6 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return out[:, :, :s]
 
 
-def _use_flash_kernel(cfg: ModelConfig) -> bool:
-    """Pallas flash kernel for self-attention: on TPU by default, opt-in
-    elsewhere (interpret mode; tests force it)."""
-    if cfg.use_flash is not None:
-        return cfg.use_flash
-    return jax.default_backend() == "tpu"
-
-
 # ---------------------------------------------------------------------------
 # GQA attention block (supports SWA + self/cross + KV cache)
 # ---------------------------------------------------------------------------
@@ -229,19 +221,25 @@ def gqa_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
             kv_pos = jnp.arange(k.shape[2], dtype=jnp.int32)
             o = attend(q, k, v, q_pos=positions, kv_pos=kv_pos,
                        causal=False, q_chunk=cfg.attn_q_chunk)
-        elif _use_flash_kernel(cfg) and (mode != "train" or cfg.use_flash):
-            # Pallas flash kernel (TPU target): native GQA, VMEM-tiled —
-            # no KV-head repeat, no score-tile HBM traffic.  Default for
-            # inference modes; training keeps the rematerialized XLA path
-            # until the backward kernel lands (the fwd kernel has no vjp).
-            from repro.kernels.flash_attention import ops as flash_ops
-            o = flash_ops.flash_attention(
-                q, k, v, causal=causal, window=cfg.window,
-                force="pallas" if cfg.use_flash else None)
         else:
-            o = attend(q, k, v, q_pos=positions, kv_pos=positions,
-                       causal=causal, window=cfg.window,
-                       q_chunk=cfg.attn_q_chunk)
+            # Pallas flash kernel (TPU target): native GQA, VMEM-tiled —
+            # no KV-head repeat, no score-tile HBM traffic.  Inference
+            # modes only: training keeps the rematerialized XLA path until
+            # a backward kernel lands (the fwd kernel has no vjp).
+            from repro.kernels import dispatch
+            from repro.kernels.flash_attention import kernel as fk
+
+            impl = dispatch.choose("flash_attention", fits=mode != "train",
+                                   vmem_bytes=fk.vmem_bytes(dh),
+                                   want=cfg.use_flash)
+            if impl != "xla":
+                o = fk.flash_attention_pallas(
+                    q, k, v, causal=causal, window=cfg.window,
+                    interpret=impl == "interpret")
+            else:
+                o = attend(q, k, v, q_pos=positions, kv_pos=positions,
+                           causal=causal, window=cfg.window,
+                           q_chunk=cfg.attn_q_chunk)
         if mode == "prefill" and not is_cross:
             new_cache = _build_prefill_cache(
                 cfg, k, v, positions, cache_len or k.shape[2])

@@ -238,8 +238,7 @@ def _moe_ffn_ep_shardmap(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                 jax.lax.pmean(v, dp_axes) for v in metrics)
         return y, metrics[0], metrics[1], metrics[2]
 
-    from repro.core.context import compat_shard_map
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(bspec, None, None), P(), P(), P()),

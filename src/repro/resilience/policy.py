@@ -17,7 +17,9 @@ Retry taxonomy: the **fatal** tuple (``ValueError``/``TypeError``/...)
 fails fast — those are programming or corruption errors where a retry
 re-runs the same deterministic failure (``HptIntegrityError`` and
 ``CorruptFragmentError`` are ``ValueError`` subclasses precisely so
-corruption is never retried).  Everything else is presumed transient
+corruption is never retried).  ``jax.errors.JaxRuntimeError`` is fatal
+too: a kernel the TPU compiler refuses or a device out of memory fails
+the same way on every attempt.  Everything else is presumed transient
 (``OSError``, ``RuntimeError``) unless an explicit ``retryable`` tuple
 narrows it.  Exhausted budgets raise :class:`RetryBudgetExceeded`,
 itself classified fatal so nested policies never multiply retries.
@@ -29,6 +31,8 @@ import time
 import zlib
 from typing import Callable, Optional, Tuple
 
+import jax
+
 
 class RetryBudgetExceeded(RuntimeError):
     """A site failed on every attempt the policy allowed.  ``__cause__``
@@ -38,7 +42,8 @@ class RetryBudgetExceeded(RuntimeError):
 
 
 _DEFAULT_FATAL = (ValueError, TypeError, KeyError, AttributeError,
-                  NotImplementedError, AssertionError, RetryBudgetExceeded)
+                  NotImplementedError, AssertionError, RetryBudgetExceeded,
+                  jax.errors.JaxRuntimeError)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -129,8 +129,7 @@ def embed_lookup(embed, tokens):
     def local(e, t):
         return e[t]
 
-    from repro.core.context import compat_shard_map
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, d_axis), P(b_spec, None)),
         out_specs=P(b_spec, None, d_axis))

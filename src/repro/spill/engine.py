@@ -36,7 +36,6 @@ import math
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
@@ -276,6 +275,16 @@ def _empty_cols(schema: Dict[str, Tuple]) -> Dict[str, np.ndarray]:
             for k, (dt, tr) in schema.items()}
 
 
+def _host_table(cols: Dict[str, np.ndarray], n: int, capacity: int
+                ) -> Table:
+    """Host columns padded to ``capacity`` rows.  They stay on the host
+    until :meth:`DistTable.from_shard_tables` puts each shard on its own
+    device."""
+    return Table({k: np.pad(v, [(0, capacity - v.shape[0])]
+                            + [(0, 0)] * (v.ndim - 1))
+                  for k, v in cols.items()}, n)
+
+
 def _round_capacity(rows: int, budget_rows: int) -> int:
     """Pad capacities to budget multiples so jit traces are reused."""
     return budget_rows * max(1, math.ceil(rows / budget_rows))
@@ -295,9 +304,7 @@ def _load_hash_partition(store: SpillStore, tag: str, q: int,
                 cols = _empty_cols(schema)
             cols.pop(H1_NAME, None)
             cols.pop(H2_NAME, None)
-            tables.append(Table.from_arrays(
-                {k: jnp.asarray(v) for k, v in cols.items()},
-                num_rows=n, capacity=capacity))
+            tables.append(_host_table(cols, n, capacity))
         sp.attrs["rows"] = total
         return DistTable.from_shard_tables(
             tables, ctx, partitioning=(tuple(keys), ctx.n_shards))
@@ -327,9 +334,8 @@ def _load_range_partition(store: SpillStore, tag: str, q: int,
         tables = []
         for s in range(ctx.n_shards):
             a, b = min(s * per, n), min((s + 1) * per, n)
-            tables.append(Table.from_arrays(
-                {k: jnp.asarray(v[a:b]) for k, v in cols.items()},
-                num_rows=b - a, capacity=capacity))
+            tables.append(_host_table({k: v[a:b] for k, v in cols.items()},
+                                      b - a, capacity))
         return DistTable.from_shard_tables(
             tables, ctx,
             partitioning=range_partitioning(keys, ascending, ctx.n_shards))
@@ -409,9 +415,7 @@ class SpillResult:
                 cols, n = self._store.read_partition("out", q, s)
                 if n == 0:
                     cols = _empty_cols(self._out_schema)
-                tables.append(Table.from_arrays(
-                    {k: jnp.asarray(v) for k, v in cols.items()},
-                    num_rows=n, capacity=cap))
+                tables.append(_host_table(cols, n, cap))
             yield DistTable.from_shard_tables(
                 tables, self._ctx, partitioning=self._partitioning)
             if drop:
@@ -422,9 +426,8 @@ class SpillResult:
         the stand-in result when no partition produced rows (e.g. an
         inner join with no matches)."""
         cols = _empty_cols(self._out_schema)
-        tables = [Table.from_arrays(
-            {k: jnp.asarray(v) for k, v in cols.items()},
-            num_rows=0, capacity=1) for _ in range(self._ctx.n_shards)]
+        tables = [_host_table(cols, 0, 1)
+                  for _ in range(self._ctx.n_shards)]
         return DistTable.from_shard_tables(
             tables, self._ctx, partitioning=self._partitioning)
 

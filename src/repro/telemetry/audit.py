@@ -127,15 +127,28 @@ def top_collectives(hlo_text: str, k: int = 12
 
 
 def compiled_collectives(fn, *args) -> Dict[str, Any]:
-    """Compile ``fn`` (no execution) → observed HLO collective stats."""
+    """Compile ``fn`` (no execution) → observed HLO collective stats.
+
+    ``ring_cost_s`` prices the ring model at the link bandwidth of the
+    device the program compiled for (``core.device.peaks``); it is
+    ``None`` off a TPU, where there are no links to price."""
     import jax
+
+    from repro.core.device import peaks
+    from repro.launch.roofline import DRYRUN_KIND
 
     compiled = jax.jit(fn).lower(*args).compile()
     stats = hlo_collectives(compiled.as_text())
+    dev = jax.devices()[0]
+    ring_cost_s = None
+    if dev.platform == "tpu":
+        # hlo_collectives prices links at the dry-run chip's bandwidth
+        ring_cost_s = stats.cost_s * (peaks(DRYRUN_KIND).ici_bw
+                                      / peaks(dev.device_kind).ici_bw)
     return {"counts": dict(stats.counts),
             "bytes_by_kind": dict(stats.bytes_by_kind),
             "total_bytes": stats.total_bytes,
-            "ring_cost_s": stats.cost_s}
+            "ring_cost_s": ring_cost_s}
 
 
 def program_audit(fn, *args, n_shards: int = 1,

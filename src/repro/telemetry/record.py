@@ -24,13 +24,12 @@ from typing import Any, Dict, List, Optional
 
 
 def tracing() -> bool:
-    """True while jax is tracing — spans must not materialize then."""
-    try:
-        import jax.core
+    """True while jax is staging or transforming a program (jit,
+    make_jaxpr, eval_shape, grad, vmap) — spans must not materialize
+    then.  Reads the current trace; allocates nothing."""
+    import jax.core
 
-        return not jax.core.trace_state_clean()
-    except Exception:  # unknown jax internals: assume unsafe, skip spans
-        return True
+    return not jax.core.trace_ctx.is_top_level()
 
 
 class Span:

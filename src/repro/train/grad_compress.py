@@ -35,8 +35,7 @@ def ef_allreduce_mean(x: jnp.ndarray, err: jnp.ndarray, axis: str,
     Returns (averaged value, new error state). x/err are the local shard's
     full gradient leaf (replicated shape across the axis).
     """
-    from repro.core.array_ops import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     xe = x.astype(jnp.float32) + err
     # pad flat length to a multiple of the axis size
     flat = xe.reshape(-1)

@@ -137,8 +137,7 @@ def test_grad_compression_ef_allreduce():
         def f(g, e):
             return ef_allreduce_mean(g[0], e[0], "pod")
 
-        from repro.core.context import compat_shard_map
-        fn = compat_shard_map(lambda g, e: tuple(
+        fn = jax.shard_map(lambda g, e: tuple(
                  x[None] for x in ef_allreduce_mean(g[0], e[0], "pod")),
                  mesh=mesh, in_specs=(P("pod"), P("pod")),
                  out_specs=(P("pod"), P("pod")))

@@ -46,7 +46,7 @@ def test_pack_unpack_roundtrip_bit_exact():
         jnp.nan)
     buf, specs = ex.pack_columns(cols)
     assert buf.dtype == jnp.uint32
-    assert buf.shape == (97, 1 + 1 + 1 + 1 + 3)
+    assert buf.shape == (1 + 1 + 1 + 1 + 3, 97)     # lanes-major
     back = ex.unpack_columns(buf, specs)
     assert set(back) == set(cols)
     for k in cols:
@@ -170,7 +170,7 @@ def test_hash_partition_return_hashes_bit_equal():
     cols = [jnp.asarray(RNG.integers(0, 1000, n), jnp.int32),
             jnp.asarray(RNG.normal(size=n), jnp.float32)]
     valid = jnp.asarray(RNG.random(n) < 0.8)
-    keys = jnp.stack([_as_u32(c) for c in cols], axis=1)
+    keys = jnp.stack([_as_u32(c) for c in cols])
     dg, hg, h1g, h2g = hk.hash_partition_pallas(
         keys, valid, p, interpret=True, block_n=128, return_hashes=True)
     de, he, h1e, h2e = hr.hash_partition_full(cols, p, valid)
@@ -185,14 +185,20 @@ def test_hash_partition_return_hashes_bit_equal():
 
 
 def test_hash_partition_ops_dispatcher_force_pallas():
-    from repro.kernels.hash_partition import ops as hpops
+    from repro.core.table import _as_u32
+    from repro.kernels import dispatch
+    from repro.kernels.hash_partition import kernel as hk, ops as hpops
 
     n, p = 100, 4
     col = jnp.asarray(RNG.integers(0, 50, n), jnp.int32)
     valid = jnp.ones((n,), bool)
+    dispatch.reset_counts()
     d1, h1 = hpops.hash_partition([col], p, valid)
-    d2, h2, a, b = hpops.hash_partition([col], p, valid, force="pallas",
-                                        return_hashes=True)
+    # off a TPU the one dispatch rule takes the XLA reference
+    assert dispatch.counts()["hash_partition"] == {"xla": 1}
+    d2, h2, a, b = hk.hash_partition_pallas(
+        jnp.stack([_as_u32(col)]), valid, p, interpret=True,
+        return_hashes=True)
     np.testing.assert_array_equal(d1, d2)
     np.testing.assert_array_equal(h1, h2)
     e1, e2 = hash_columns([col])

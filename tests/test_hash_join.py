@@ -1,6 +1,6 @@
 """Sort-free hash-join engine vs the sort-merge oracle (DESIGN.md §8).
 
-Four layers of guarantees:
+Three layers of guarantees:
 
   * parity — ``method="hash"`` output equals ``method="sort"`` bit-exactly
     on valid rows (as multisets) for all four ``how`` modes, duplicate
@@ -8,8 +8,6 @@ Four layers of guarantees:
     with equal overflow counts;
   * sort-freedom — the traced jaxpr of the hash join path and of every
     set operator contains zero ``sort`` primitives;
-  * kernel — the Pallas fused-probe kernel (interpret mode) is bit-equal
-    to the jnp reference;
   * overflow contract — fan-out beyond ``max_matches``/``max_probes`` is
     counted, never silently dropped (§2).
 """
@@ -29,7 +27,6 @@ except ImportError:  # tier-1 env may lack hypothesis: skip only @given tests
     from conftest import given, settings, st
 
 from repro.core import DistTable, Table, local_context, table_ops
-from repro.core.exchange import key_compare_u32
 from repro.core.table import hash_columns
 from repro.dataframe.frame import DataFrame
 from repro.kernels.hash_join import ops as hjops
@@ -244,37 +241,6 @@ def test_setops_nan_rows_bitwise():
     i, _ = table_ops.intersect(a, b, ctx=CTX)
     got = i.to_numpy()["x"]
     assert len(got) == 1 and np.isnan(got[0])
-
-
-# ---------------------------------------------------------------------------
-# kernel: Pallas (interpret) vs jnp reference, bit-exact
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("mm", [1, 4])
-def test_probe_kernel_interpret_matches_ref(mm):
-    n_build, n_probe = 700, 900
-    bcols = {"k": jnp.asarray(RNG.integers(0, 60, n_build).astype(np.int32)),
-             "f": jnp.asarray(KEY_POOL[RNG.integers(0, len(KEY_POOL),
-                                                    n_build)])}
-    pcols = {"k": jnp.asarray(RNG.integers(0, 70, n_probe).astype(np.int32)),
-             "f": jnp.asarray(KEY_POOL[RNG.integers(0, len(KEY_POOL),
-                                                    n_probe)])}
-    keys = ("k", "f")
-    bh1, bh2 = hash_columns([bcols[k] for k in keys])
-    ph1, ph2 = hash_columns([pcols[k] for k in keys])
-    bkeys = key_compare_u32(bcols, keys)
-    pkeys = key_compare_u32(pcols, keys)
-    bmask = jnp.arange(n_build) < 640
-    pmask = jnp.arange(n_probe) < 850
-    table, unplaced = hjops.build_table(bh1, bh2, bmask, 4096, 64)
-    assert int(unplaced) == 0
-    slot_h2, slot_keys = hjops.slot_payload(table, bh2, bkeys)
-    ref = hjops.probe(table, slot_h2, slot_keys, ph1, ph2, pkeys, pmask,
-                      mm, 64)
-    pal = hjops.probe(table, slot_h2, slot_keys, ph1, ph2, pkeys, pmask,
-                      mm, 64, force="pallas")
-    for x, y, name in zip(ref, pal, ("cnt", "rimat", "exhausted")):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                      err_msg=name)
 
 
 def test_build_table_every_valid_row_has_a_slot():

@@ -96,7 +96,7 @@ def test_schema_matches_packer_layout():
     buf, specs = pack_columns(cols)
     schema = Schema.from_columns(cols)
     assert schema.to_colspecs() == specs
-    assert schema.row_width == buf.shape[1]
+    assert schema.row_width == buf.shape[0]
     # bidirectional: specs -> schema -> specs round trip
     assert Schema.from_colspecs(specs).to_colspecs() == specs
     # and unpack still inverts under the schema-derived specs

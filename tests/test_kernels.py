@@ -122,7 +122,7 @@ def test_hash_partition_vs_ref(n, k, p):
         else:
             cols.append(jnp.asarray(RNG.integers(0, 1000, n), jnp.int32))
     valid = jnp.asarray(RNG.random(n) < 0.8)
-    keys = jnp.stack([_as_u32(c) for c in cols], axis=1)
+    keys = jnp.stack([_as_u32(c) for c in cols])
     dg, hg = hk.hash_partition_pallas(keys, valid, p, interpret=True,
                                       block_n=128)
     de, he = hr.hash_partition(cols, p, valid)

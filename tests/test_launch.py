@@ -88,6 +88,13 @@ def test_roofline_terms_and_bottleneck():
     assert r.mfu == pytest.approx(0.5)
 
 
+def test_peaks_table_keyed_by_device_kind():
+    v5e = rl.peaks("TPU v5 lite")
+    assert v5e.bf16_flops == 197e12 and v5e.hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        rl.peaks("cpu")
+
+
 def test_shape_bytes_parser():
     assert rl._shape_bytes("bf16[16,128]{1,0}") == 16 * 128 * 2
     assert rl._shape_bytes("(f32[8]{0}, s32[4]{0})") == 8 * 4 + 4 * 4

@@ -213,14 +213,15 @@ def test_segment_reduce_fused_matches_per_column():
 
     n, s = 999, 64
     seg = jnp.asarray(RNG.integers(0, s + 2, n).astype(np.int32))  # + oob
-    vals = jnp.asarray(RNG.normal(size=(n, 3)).astype(np.float32))
+    vals = jnp.asarray(RNG.normal(size=(3, n)).astype(np.float32))
     fused = segops.segment_reduce_fused(vals, seg, s)
     for lane in range(3):
-        exp = segops.segment_reduce(vals[:, lane], seg, s, op="sum")
-        np.testing.assert_allclose(fused[:, lane], exp, rtol=1e-5,
+        exp = segops.segment_reduce(vals[lane], seg, s, op="sum")
+        np.testing.assert_allclose(fused[lane], exp, rtol=1e-5,
                                    atol=1e-5)
     # Pallas interpret-mode kernel vs the jnp reference
-    interp = segops.segment_reduce_fused(vals, seg, s, force="pallas")
+    from repro.kernels.segment_reduce import kernel as sk
+    interp = sk.segment_reduce_pallas(vals, seg, s, "sum", interpret=True)
     np.testing.assert_allclose(interp, fused, rtol=1e-5, atol=1e-5)
 
 

@@ -158,6 +158,21 @@ def test_policy_fatal_fails_fast_no_retry():
     assert calls["n"] == 1                      # never retried
 
 
+def test_policy_jax_runtime_error_is_fatal():
+    """A refused kernel or a device OOM fails the same way every time."""
+    calls = {"n": 0}
+
+    def oom():
+        calls["n"] += 1
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    pol = FaultPolicy(max_retries=5)
+    assert not pol.is_retryable(jax.errors.JaxRuntimeError("x"))
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        pol.run(oom, site="t", sleep=lambda s: None)
+    assert calls["n"] == 1
+
+
 def test_policy_budget_exhaustion_is_itself_fatal():
     pol = FaultPolicy(max_retries=2)
 

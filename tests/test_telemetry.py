@@ -87,6 +87,29 @@ def test_jit_internal_operator_calls_emit_nothing():
     assert "table.shuffle.calls" not in rec.metrics.counters
 
 
+def test_tracing_is_false_eagerly_and_true_under_every_transform():
+    def probe(seen):
+        def f(x):
+            seen.append(telemetry.tracing())
+            return x
+        return f
+
+    x = np.float32(1.0)
+    transforms = {
+        "jit": lambda f: jax.jit(f)(x),
+        "make_jaxpr": lambda f: jax.make_jaxpr(f)(x),
+        "eval_shape": lambda f: jax.eval_shape(f, x),
+        "grad": lambda f: jax.grad(f)(x),
+        "vmap": lambda f: jax.vmap(f)(np.ones(2, np.float32)),
+    }
+    assert not telemetry.tracing()
+    for name, run in transforms.items():
+        seen = []
+        run(probe(seen))
+        assert seen == [True], name
+    assert not telemetry.tracing()
+
+
 def test_nested_traces_stack():
     with telemetry.trace("outer") as outer:
         with outer.span("a"):

@@ -295,7 +295,7 @@ def test_window_property(keys, vals, rows):
 # Pallas windowed scan: interpret mode is bit-identical to the reference
 # ---------------------------------------------------------------------------
 def test_windowed_scan_pallas_bit_equality():
-    from repro.kernels.window_scan import ops as wops
+    from repro.kernels.window_scan import kernel as wk, ops as wops
 
     n = 1111
     vals = jnp.asarray(RNG.normal(size=(n, 3)).astype(np.float32))
@@ -304,10 +304,10 @@ def test_windowed_scan_pallas_bit_equality():
     flags[np.sort(RNG.choice(np.arange(1, n), 40, replace=False))] = True
     seg = jnp.asarray(np.maximum.accumulate(
         np.where(flags, np.arange(n), 0)).astype(np.int32))
-    for w in (1, 7, 64, 512):
+    for w in (1, 7, 64, 512, 1500):
         for op in ("sum", "min", "max"):
             ref = wops.windowed_scan(vals, seg, w, op)
-            pal = wops.windowed_scan(vals, seg, w, op, force="pallas")
+            pal = wk.windowed_scan_pallas(vals, seg, w, op, interpret=True)
             np.testing.assert_array_equal(np.asarray(ref), np.asarray(pal),
                                           err_msg=f"w={w} op={op}")
 
