@@ -286,7 +286,7 @@ class DistTable:
         """Block-partition a local table's valid rows across shards.
 
         On a mesh the blocks are cut on the host and each goes straight
-        to its own device (:meth:`_placed`), so no device ever holds the
+        to its own device (:meth:`placed`), so no device ever holds the
         whole table."""
         p = ctx.n_shards
         n = int(table.num_rows)
@@ -305,7 +305,7 @@ class DistTable:
             cols[k] = h
         counts = np.clip(n - np.arange(p) * per, 0, per)
         counts = np.minimum(counts, cap).astype(np.int32)
-        return cls._placed(cols, counts, ctx)
+        return cls.placed(cols, counts, ctx)
 
     @classmethod
     def from_shard_tables(cls, tables: Sequence[Table], ctx: HPTMTContext,
@@ -317,9 +317,19 @@ class DistTable:
         scan to place on-disk shard files back onto their shards —
         ``partitioning`` is attached verbatim, so callers assert the layout
         evidence truthfully (DESIGN.md §4/§5).  Columns may be host
-        (numpy) arrays; the blocks are padded and joined on the host and
-        each is placed on its own device.
+        (numpy) arrays; the blocks are padded and joined on the host
+        (:meth:`shard_blocks`) and each is placed on its own device
+        (:meth:`placed`).
         """
+        cols, counts = cls.shard_blocks(tables, ctx)
+        return cls.placed(cols, counts, ctx, partitioning)
+
+    @staticmethod
+    def shard_blocks(tables: Sequence[Table], ctx: HPTMTContext
+                     ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
+        """The host half of :meth:`from_shard_tables`: every shard's
+        columns padded to the common capacity and joined in shard order,
+        and the shards' row counts."""
         if len(tables) != ctx.n_shards:
             raise ValueError(f"{len(tables)} shard tables for a "
                              f"{ctx.n_shards}-shard context")
@@ -339,12 +349,12 @@ class DistTable:
                 for k in names}
         counts = np.array([min(int(t.num_rows), cap) for t in tables],
                           np.int32)
-        return cls._placed(cols, counts, ctx, partitioning)
+        return cols, counts
 
     @classmethod
-    def _placed(cls, cols: Dict[str, np.ndarray], counts: np.ndarray,
-                ctx: HPTMTContext, partitioning: Partitioning = None
-                ) -> "DistTable":
+    def placed(cls, cols: Dict[str, np.ndarray], counts: np.ndarray,
+               ctx: HPTMTContext, partitioning: Partitioning = None
+               ) -> "DistTable":
         """Host columns → device arrays, each row block on its own device."""
         if ctx.mesh is None:
             return cls({k: jnp.asarray(v) for k, v in cols.items()},

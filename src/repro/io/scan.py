@@ -199,16 +199,13 @@ class ScanSource:
                     if not (pr.op == "!="
                             and self.dataset.schema[pr.column].np_dtype.kind
                             == "f")]
-        with telemetry.span("io.scan.prune",
-                            fragments=len(self.dataset.fragments)) as sp:
-            kept: List[Fragment] = []
-            for frag in self.dataset.fragments:
-                if all(pr.maybe_satisfied(frag.stats.get(pr.column))
-                       for pr in prunable):
-                    kept.append(frag)
-            self.stats.row_groups_skipped = (
-                len(self.dataset.fragments) - len(kept))
-            sp.attrs["pruned"] = self.stats.row_groups_skipped
+        kept: List[Fragment] = []
+        for frag in self.dataset.fragments:
+            if all(pr.maybe_satisfied(frag.stats.get(pr.column))
+                   for pr in prunable):
+                kept.append(frag)
+        self.stats.row_groups_skipped = (
+            len(self.dataset.fragments) - len(kept))
         self.stats.columns_read = len(self.read_columns) if kept else 0
 
         # partitioned re-entry: manifest evidence + matching context +
@@ -383,11 +380,10 @@ class ScanSource:
         return {c: np.zeros((0,) + schema[c].trailing, schema[c].np_dtype)
                 for c in self.out_columns}, 0
 
-    def _shard_table(self, frags: Sequence[Fragment],
+    def _shard_table(self, parts: List[Tuple[Dict[str, np.ndarray], int]],
                      capacity: int) -> Tuple[Table, int]:
-        """Concatenate a shard's fragments (original row order), truncate
-        at ``capacity`` per the §2 count-and-drop contract."""
-        parts = self._load_fragments(frags) if frags else []
+        """Concatenate a shard's loaded fragments (original row order),
+        truncate at ``capacity`` per the §2 count-and-drop contract."""
         if not parts:
             cols, n = self._empty_shard()
         else:
@@ -410,13 +406,20 @@ class ScanSource:
         tables = []
         with telemetry.span("io.scan.materialize",
                             shards=self.ctx.n_shards) as sp:
-            for frags in self._by_shard:
-                t, ov = self._shard_table(frags, self.shard_capacity)
-                tables.append(t)
-                overflow += ov
-            dt = DistTable.from_shard_tables(tables, self.ctx,
-                                             partitioning=self._partitioning)
-            sp.block(dt)
+            loaded = [self._load_fragments(frags) if frags else []
+                      for frags in self._by_shard]
+            with telemetry.span("io.scan.assemble"):
+                while loaded:  # a shard's read columns go once assembled
+                    t, ov = self._shard_table(loaded.pop(0),
+                                              self.shard_capacity)
+                    tables.append(t)
+                    overflow += ov
+                cols, counts = DistTable.shard_blocks(tables, self.ctx)
+            nbytes = sum(v.nbytes for v in cols.values()) + counts.nbytes
+            with telemetry.span("io.scan.upload", bytes=nbytes) as up:
+                dt = DistTable.placed(cols, counts, self.ctx,
+                                      self._partitioning)
+                up.block(dt)
             sp.attrs["rows"] = self.stats.rows_selected
             sp.attrs["overflow"] = overflow
         if self.quarantined:
@@ -455,7 +458,7 @@ class ScanSource:
                         {k: _host_column(k, v, self.allow_narrowing, cap)
                          for k, v in cols.items()}, 0))
                 else:
-                    t, _ = self._shard_table([f], cap)
+                    t, _ = self._shard_table(self._load_fragments([f]), cap)
                     tables.append(t)
             yield DistTable.from_shard_tables(
                 tables, self.ctx, partitioning=self._partitioning)

@@ -149,6 +149,13 @@ class LazyFrame:
         ``plan.<step>`` labels and raises unless ``strict=False`` — the
         same §2 contract as the eager operators.
 
+        Whenever a collector is active (``telemetry``, or one the caller
+        activated with ``telemetry.using``), the query is one
+        ``plan.collect`` span numbered per collector (``query``) holding
+        ``plan.optimize``, the scans, ``plan.jit`` (trace, lower, compile
+        or cache load, enqueue) and ``plan.wait`` (until the overflow
+        counters reach the host); no span adds a wait.
+
         ``telemetry`` accepts a :class:`repro.telemetry.Collector`: the
         run then records spans (per physical node when ``jit=False`` —
         inside one jitted program the host clock cannot attribute time
@@ -179,35 +186,53 @@ class LazyFrame:
         ``jit`` is ignored; without a policy this path adds nothing —
         no stage I/O, no extra tracing.
         """
+        import contextlib
         import time
 
         import jax
 
+        from repro import telemetry as T
         from repro.dataframe.frame import DataFrame
 
-        root, _ = optimize(self._node)
-        plan = PhysicalPlan(root, self._ctx)
-        fingerprint = None
-        if policy is not None or ledger is not None:
-            from repro.resilience import stages as S
+        rec = telemetry if telemetry is not None else T.current()
+        active = (T.using(telemetry) if telemetry is not None
+                  else contextlib.nullcontext())
+        with active, T.span("plan.collect", jit=jit) as top:
+            if rec is not None:
+                rec.queries += 1
+                top.attrs["query"] = rec.queries
+            with T.span("plan.optimize"):
+                root, _ = optimize(self._node)
+                plan = PhysicalPlan(root, self._ctx)
+            top.attrs.update(steps=len(plan.steps),
+                             predicted_a2a=plan.predicted_collectives)
+            fingerprint = None
+            if policy is not None or ledger is not None:
+                from repro.resilience import stages as S
 
-            fingerprint = S.plan_fingerprint(root, self._ctx)
-        t0 = time.perf_counter()
-        if policy is not None:
-            out, ovs = self._collect_resilient(plan, policy, telemetry,
-                                               fingerprint)
-        elif telemetry is not None:
-            out, ovs = self._collect_audited(plan, telemetry, jit=jit,
-                                             strict=strict)
-        else:
-            inputs = plan.inputs()
-            fn = jax.jit(plan.fn) if jit else plan.fn
-            out, ovs = fn(*inputs)
-        wall_s = time.perf_counter() - t0
-        report = OverflowReport().merge(self._report)
-        report.add("plan.scan.capacity", plan.scan_overflow)
-        for label, v in sorted(ovs.items()):
-            report.add(f"plan.{label}", int(v))
+                fingerprint = S.plan_fingerprint(root, self._ctx)
+            t0 = time.perf_counter()
+            if policy is not None:
+                out, ovs = self._collect_resilient(plan, policy, telemetry,
+                                                   fingerprint)
+            else:
+                inputs = plan.inputs()
+                if jit:
+                    # trace, lower, compile or load, enqueue: no wait
+                    with T.span("plan.jit"):
+                        out, ovs = jax.jit(plan.fn)(*inputs)
+                else:
+                    out, ovs = plan.fn(*inputs)
+                if telemetry is not None:
+                    self._audit(plan, inputs, telemetry, strict=strict)
+            wall_s = time.perf_counter() - t0
+            top.attrs["rows_scanned"] = plan.rows_scanned
+            report = OverflowReport().merge(self._report)
+            report.add("plan.scan.capacity", plan.scan_overflow)
+            # the first counter read waits for the program to finish
+            with T.span("plan.wait"):
+                for label, v in sorted(ovs.items()):
+                    report.add(f"plan.{label}", int(v))
         if telemetry is not None:
             from repro.telemetry import cardinality as C
 
@@ -284,7 +309,6 @@ class LazyFrame:
         restored stage replaces its whole subtree — the re-executed
         program is exactly the plan suffix after the last commit.
         """
-        import contextlib
         import shutil
         import tempfile
 
@@ -307,28 +331,19 @@ class LazyFrame:
         resumed_from = max(committed) if committed else None
         plan.stage_hook = S.stage_hook(ckpt, policy=policy, ctx=self._ctx,
                                        committed=committed, record=rec)
-        active = T.using(rec) if rec is not None else \
-            contextlib.nullcontext()
         try:
-            with active:
-                if rec is not None:
-                    for s in plan.steps:
-                        rec.observe_step(s.index, op=s.op,
-                                         strategy=s.strategy,
-                                         predicted_a2a=s.a2a,
-                                         est_rows=s.est_rows,
-                                         est_bytes=s.est_bytes)
-                    if resumed_from is not None:
-                        rec.metrics.gauge("recovery.resumed_from_stage",
-                                          resumed_from)
-                with T.span("recovery.collect", fingerprint=fingerprint,
-                            resumed_from=(-1 if resumed_from is None
-                                          else resumed_from),
-                            stages=sum(s.stage for s in plan.steps)) as sp:
-                    out, ovs = policy.run(
-                        lambda: plan.fn(*plan.inputs()),
-                        site="plan.collect")
-                    sp.block(out)
+            if rec is not None:
+                _observe_predicted(plan, rec)
+                if resumed_from is not None:
+                    rec.metrics.gauge("recovery.resumed_from_stage",
+                                      resumed_from)
+            with T.span("recovery.collect", fingerprint=fingerprint,
+                        resumed_from=(-1 if resumed_from is None
+                                      else resumed_from),
+                        stages=sum(s.stage for s in plan.steps)) as sp:
+                out, ovs = policy.run(lambda: plan.fn(*plan.inputs()),
+                                      site="plan.collect")
+                sp.block(out)
         finally:
             plan.stage_hook = None
         if not policy.keep_checkpoints:
@@ -337,25 +352,13 @@ class LazyFrame:
             shutil.rmtree(tmp_root, ignore_errors=True)
         return out, ovs
 
-    def _collect_audited(self, plan: PhysicalPlan, rec, *, jit: bool,
-                         strict: bool):
-        """Run ``plan`` under collector ``rec``: root span + per-step
-        predicted facts + the three-layer collective audit."""
-        import jax
-
+    def _audit(self, plan: PhysicalPlan, inputs, rec, *, strict: bool):
+        """File ``plan``'s per-step predicted facts on collector ``rec``
+        and run the three-layer collective audit (it lowers the program
+        again)."""
         from repro import telemetry as T
 
-        for s in plan.steps:
-            rec.observe_step(s.index, op=s.op, strategy=s.strategy,
-                             predicted_a2a=s.a2a, est_rows=s.est_rows,
-                             est_bytes=s.est_bytes)
-        with T.using(rec):
-            with rec.span("plan.collect", steps=len(plan.steps), jit=jit,
-                          predicted_a2a=plan.predicted_collectives) as sp:
-                inputs = plan.inputs()
-                fn = jax.jit(plan.fn) if jit else plan.fn
-                out, ovs = fn(*inputs)
-                sp.block(out)
+        _observe_predicted(plan, rec)
         audit = T.program_audit(plan.fn, *inputs,
                                 n_shards=self._ctx.n_shards,
                                 predicted_a2a=plan.predicted_collectives)
@@ -381,7 +384,6 @@ class LazyFrame:
                 f"{audit['predicted_a2a']} all_to_all, jaxpr traced "
                 f"{audit['traced_a2a']}, compiled HLO observed "
                 f"{audit['observed_a2a']} — the plan contract is broken")
-        return out, ovs
 
     def explain(self, *, optimized: bool = True,
                 analyze: bool = False) -> str:
@@ -410,6 +412,15 @@ class LazyFrame:
         return render_explain(self._node, root, fired, plan,
                               annotations=plan_annotations(rec),
                               audit=audit)
+
+
+def _observe_predicted(plan: PhysicalPlan, rec) -> None:
+    """Every step's predicted facts, for ``explain(analyze=True)`` and
+    the cardinality audit to join with what the run observes."""
+    for s in plan.steps:
+        rec.observe_step(s.index, op=s.op, strategy=s.strategy,
+                         predicted_a2a=s.a2a, est_rows=s.est_rows,
+                         est_bytes=s.est_bytes)
 
 
 class LazyWindow:

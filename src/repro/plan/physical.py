@@ -47,6 +47,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro import telemetry
@@ -160,6 +161,7 @@ class PhysicalPlan:
         self._input_specs: List[Tuple[str, object]] = []
         self._materialized: Optional[Tuple[DistTable, ...]] = None
         self.scan_overflow = 0
+        self.rows_scanned = 0
         # resilience hook: when set (collect(policy=...)), stage-boundary
         # steps route through it — restore a committed snapshot (skipping
         # the whole subtree) or run + commit.  None (the default) keeps
@@ -177,15 +179,17 @@ class PhysicalPlan:
 
     def inputs(self) -> Tuple[DistTable, ...]:
         if self._materialized is None:
-            tables, overflow = [], 0
+            tables, overflow, scanned = [], 0, 0
             for kind, obj in self._input_specs:
                 if kind == "table":
                     tables.append(obj)
                 else:  # scan
                     dt, ov = obj.to_dist_table()
                     overflow += int(ov)
+                    scanned += obj.stats.rows_scanned
                     tables.append(dt)
             self.scan_overflow = overflow
+            self.rows_scanned = scanned
             self._materialized = tuple(tables)
         return self._materialized
 
@@ -256,23 +260,29 @@ class PhysicalPlan:
 
     def _instrument(self, run: Callable, step: PlanStep,
                     layout: Layout) -> Callable:
-        """Per-node telemetry wrapper.
+        """Per-node telemetry wrapper, named ``plan.<index>.<op>``.
 
-        Inert unless a collector is active AND the plan runs op-by-op
-        (``collect(jit=False)``): inside a jit trace the host clock lies,
-        so the wrapper passes straight through and the traced program is
-        byte-identical to the uninstrumented one.  When live, each node
-        becomes a ``plan.<index>.<op>`` span (children nested inside) and
-        its measured time/rows land in ``Collector.plan_steps`` for
-        ``explain(analyze=True)`` to join against the predicted steps.
+        While the plan is traced (``jax.jit``, ``make_jaxpr``) the node's
+        body runs under a ``jax.named_scope`` of that name: metadata on
+        its HLO ops (the op names a profiler trace shows), the same
+        computation.  Scopes nest as the closures do, so the innermost
+        ``plan.<i>.<op>`` of an op's name is its step.  Run op-by-op
+        (``collect(jit=False)``) with a collector active, each node
+        becomes a span of that name (children nested inside) and its
+        measured time/rows land in ``Collector.plan_steps`` for
+        ``explain(analyze=True)`` to join against the predicted steps;
+        with none active the wrapper passes straight through.
         """
         label = f"plan.{step.index}.{step.op}"
 
         def wrapped(tables):
             from repro.telemetry import memory as M
 
+            if telemetry.tracing():
+                with jax.named_scope(label):
+                    return run(tables)
             rec = telemetry.current()
-            if rec is None or telemetry.tracing():
+            if rec is None:
                 return run(tables)
             with M.RssWatermark() as wm:
                 with rec.span(label, op=step.op, strategy=step.strategy,

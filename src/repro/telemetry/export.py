@@ -44,7 +44,8 @@ def chrome_trace_events(collector) -> List[Dict[str, Any]]:
     Spans are complete ``X`` events placed on a per-phase lane (tid);
     ``M`` metadata events name the process (the collector) and each used
     lane; every gauge becomes one ``C`` counter sample stamped at the
-    trace end so Perfetto renders it as a counter track.
+    trace end (the latest rounded ``ts + dur``) so Perfetto renders it
+    as a counter track.
     """
     events: List[Dict[str, Any]] = []
     used_lanes = {0}
@@ -54,11 +55,11 @@ def chrome_trace_events(collector) -> List[Dict[str, Any]]:
         nonlocal end_ts
         tid = _lane(span.name)
         used_lanes.add(tid)
-        end_ts = max(end_ts, span.t0_us + span.dur_us)
+        ts, dur = round(span.t0_us, 3), round(span.dur_us, 3)
+        end_ts = max(end_ts, ts + dur)  # as a reader adds the rounded pair
         events.append({
             "name": span.name, "ph": "X", "cat": "repro",
-            "ts": round(span.t0_us, 3), "dur": round(span.dur_us, 3),
-            "pid": 0, "tid": tid,
+            "ts": ts, "dur": dur, "pid": 0, "tid": tid,
             "args": {k: _jsonable(v) for k, v in span.attrs.items()},
         })
         for c in span.children:
@@ -79,7 +80,7 @@ def chrome_trace_events(collector) -> List[Dict[str, Any]]:
 
     counters = [{
         "name": gname, "ph": "C", "cat": "repro", "pid": 0, "tid": 0,
-        "ts": round(end_ts, 3), "args": {"value": _jsonable(v)}}
+        "ts": end_ts, "args": {"value": _jsonable(v)}}
         for gname, v in sorted(collector.metrics.gauges.items())]
     return meta + events + counters
 
@@ -88,7 +89,9 @@ def export_chrome_trace(collector, path: str) -> str:
     """Write the trace to ``path`` (Perfetto-loadable); returns ``path``."""
     doc = {"traceEvents": chrome_trace_events(collector),
            "displayTimeUnit": "ms",
-           "otherData": {"collector": collector.name}}
+           # ts 0 on the wall clock, to line up with a profiler trace
+           "otherData": {"collector": collector.name,
+                         "epoch_wall_ns": collector.epoch_wall_ns}}
     with open(path, "w") as f:
         json.dump(doc, f, indent=1)
         f.write("\n")

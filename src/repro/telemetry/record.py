@@ -10,6 +10,10 @@ Every instrumentation site in the repo follows the same two-gate rule:
     (:func:`tracing` gates every open).  A span that wraps device work
     calls ``block_until_ready`` on its outputs before stamping its
     duration, so jit's async dispatch cannot make an operator look free.
+  * **one clock with the profiler** — an open span also holds a
+    ``jax.profiler.TraceAnnotation`` of its name, so a profiler trace
+    shows the program's spans beside the device ops, and each span
+    stamps its wall-clock start (``wall_ns``) to line the two up.
 
 Spans form a tree (``Collector._stack``); metrics are flat counters and
 gauges under dotted names, matching the :class:`~repro.core.report.
@@ -33,15 +37,18 @@ def tracing() -> bool:
 
 
 class Span:
-    """One timed region: name + attrs + children, µs since trace start."""
+    """One timed region: name + attrs + children.  ``t0_us``/``dur_us``
+    are ``time.perf_counter`` µs since the collector's ``epoch``;
+    ``wall_ns`` is the start on ``time.time_ns``, the profiler's clock."""
 
-    __slots__ = ("name", "attrs", "t0_us", "dur_us", "children")
+    __slots__ = ("name", "attrs", "t0_us", "dur_us", "wall_ns", "children")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
         self.t0_us = 0.0
         self.dur_us = 0.0
+        self.wall_ns = 0
         self.children: List["Span"] = []
 
     def block(self, value) -> None:
@@ -110,16 +117,22 @@ class Metrics:
 
 
 class _SpanCtx:
-    """Context manager that opens/closes one span on a collector."""
+    """Context manager that opens/closes one span on a collector, inside
+    a profiler annotation of the same name."""
 
-    __slots__ = ("_rec", "_span", "_pending")
+    __slots__ = ("_rec", "_span", "_note")
 
     def __init__(self, rec: "Collector", sp: Span):
         self._rec = rec
         self._span = sp
 
     def __enter__(self) -> Span:
+        from jax.profiler import TraceAnnotation
+
         sp = self._span
+        self._note = TraceAnnotation(sp.name)
+        self._note.__enter__()
+        sp.wall_ns = time.time_ns()
         sp.t0_us = (time.perf_counter() - self._rec.epoch) * 1e6
         self._rec._stack.append(sp)
         return sp
@@ -127,6 +140,7 @@ class _SpanCtx:
     def __exit__(self, *exc) -> None:
         sp = self._rec._stack.pop()
         sp.dur_us = (time.perf_counter() - self._rec.epoch) * 1e6 - sp.t0_us
+        self._note.__exit__(*exc)
 
 
 class Collector:
@@ -135,6 +149,8 @@ class Collector:
     def __init__(self, name: str = "trace"):
         self.name = name
         self.epoch = time.perf_counter()
+        self.epoch_wall_ns = time.time_ns()
+        self.queries = 0     # planned queries collected (plan.collect roots)
         self.spans: List[Span] = []
         self.metrics = Metrics()
         self.audits: List[Dict[str, Any]] = []
