@@ -16,7 +16,8 @@ asks :func:`choose`, which decides from what the code can observe:
 ``use_flash``): ``True`` runs the kernel — compiled on a TPU, in
 interpret mode elsewhere — and ``False`` runs the reference.
 
-Each decision is counted under ``(kernel, impl)``.  Decisions happen
+Each decision is counted under ``(kernel, impl)``; a path that has only
+one implementation counts itself with :func:`record`.  Decisions happen
 while a program is traced, so the counts say how many traced call sites
 took each implementation, not how many times a compiled program ran.
 """
@@ -57,6 +58,12 @@ def choose(kernel: str, *, fits: bool = True, vmem_bytes: int = 0,
         impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
     _COUNTS[(kernel, impl)] += 1
     return impl
+
+
+def record(kernel: str, impl: str) -> None:
+    """Count a decision the caller made itself, so that a path with no
+    alternative to choose from still shows in :func:`counts`."""
+    _COUNTS[(kernel, impl)] += 1
 
 
 def counts() -> Dict[str, Dict[str, int]]:

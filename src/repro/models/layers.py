@@ -142,6 +142,67 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return out[:, :, :s]
 
 
+def _exact_pieces(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """f32 ``x`` as pieces in ``dtype`` stacked on a new axis 0, whose sum
+    is ``x`` exactly: one piece for f32, three for bf16 (8 significant
+    bits each cover f32's 24), down to f32's normal range."""
+    n = -(-24 // (jnp.finfo(dtype).nmant + 1))
+    pieces = []
+    for _ in range(n):
+        hi = x.astype(dtype)
+        pieces.append(hi)
+        x = x - hi.astype(jnp.float32)
+    return jnp.stack(pieces)
+
+
+def attend_decode(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, *,
+                  q_pos: jnp.ndarray, kv_pos: jnp.ndarray,
+                  causal: bool = True,
+                  window: Optional[int] = None) -> jnp.ndarray:
+    """A decode step's masked attention over a KV cache, grouped by KV head.
+
+    q (B,Hq,S,D); ck, cv (B,Hkv,L,D/Dv) as the cache stores them; q_pos
+    (S,), kv_pos (L,) absolute positions (-1 = empty slot).  Query head h
+    reads KV head ``h // G`` (G = Hq // Hkv), the map of ``attend``'s
+    repeat, but the cache is read once, in its own dtype: no repeated and
+    no upcast copy of it exists.  The arithmetic is ``attend``'s after its
+    upcast — products exact, sums in f32:
+
+      * scores contract q with ck, accumulating in f32;
+      * the f32 probabilities p enter PV as pieces in the cache's dtype
+        that sum to p exactly (``_exact_pieces``), stacked on the group
+        axis so that one contraction reads cv once; the pieces' f32
+        partial sums are then added.
+
+    A single query over the cache needs no query chunks and no
+    rematerialisation, and serving shards no head axis, so nothing asks
+    for ``attend``'s repeat here.
+    """
+    from repro.kernels import dispatch
+
+    dispatch.record("decode_attention", "grouped")
+    b, hq, s, d = q.shape
+    hkv, l = ck.shape[1], ck.shape[2]
+    dv = cv.shape[-1]
+    g = hq // hkv
+    qg = q.reshape(b, hkv, g, s, d)
+    scores = jnp.einsum("bkgsd,bkld->bkgsl", qg, ck,
+                        preferred_element_type=jnp.float32) * d ** -0.5
+    allow = _mask_for_chunk(q_pos, kv_pos, causal, window)[None, None, None]
+    scores = jnp.where(allow, scores, -1e30)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    p = jnp.where(allow, jnp.exp(scores - m), 0.0)
+    denom = jnp.sum(p, axis=-1, keepdims=True)
+    pieces = _exact_pieces(p, cv.dtype)                 # (n,B,Hkv,G,S,L)
+    n = pieces.shape[0]
+    pieces = jnp.moveaxis(pieces, 0, 2).reshape(b, hkv, n * g, s, l)
+    o = jnp.einsum("bkgsl,bkld->bkgsd", pieces, cv,
+                   preferred_element_type=jnp.float32)
+    o = o.reshape(b, hkv, n, g, s, dv).sum(axis=2)
+    o = o / jnp.maximum(denom, 1e-30)
+    return o.reshape(b, hq, s, dv).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # GQA attention block (supports SWA + self/cross + KV cache)
 # ---------------------------------------------------------------------------
@@ -214,8 +275,8 @@ def gqa_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
         cursor = (slot + s) % cache_len if cfg.window else slot + s
         new_cache = {**stored, "pos": cpos,
                      "cursor": jnp.asarray(cursor, jnp.int32)}
-        o = attend(q, ck, cv, q_pos=positions, kv_pos=cpos, causal=causal,
-                   window=cfg.window, q_chunk=cfg.attn_q_chunk)
+        o = attend_decode(q, ck, cv, q_pos=positions, kv_pos=cpos,
+                          causal=causal, window=cfg.window)
     else:
         if is_cross:
             kv_pos = jnp.arange(k.shape[2], dtype=jnp.int32)

@@ -2,6 +2,7 @@
 family-preserving reduced config, runs one forward + one train step on CPU
 with shape assertions and NaN checks; plus prefill↔decode consistency."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +10,9 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get_config, reduced_config
+from repro.kernels import dispatch
 from repro.models import transformer as T
+from repro.models.layers import attend, attend_decode
 from repro.train.optimizer import OptimizerConfig
 from repro.train.train_step import TrainConfig, init_train_state, \
     make_train_step
@@ -58,9 +61,24 @@ def test_smoke_train_step(arch):
     assert max(jax.tree.leaves(delta)) > 0
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+SMOLLM_HEADS = "smollm-360m-heads-15x5"
+
+
+def _smollm_heads():
+    """SmolLM reduced in width but with its 15/5 heads: three query heads
+    per KV head over five KV heads."""
+    full = get_config("smollm-360m")
+    cfg = reduced_config(full)
+    d_model = full.n_heads * cfg.d_head
+    return dataclasses.replace(cfg, n_heads=full.n_heads,
+                               n_kv_heads=full.n_kv_heads, d_model=d_model,
+                               d_ff=4 * d_model)
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS + [SMOLLM_HEADS])
 def test_prefill_decode_consistency(arch):
-    cfg = reduced_config(get_config(arch))
+    cfg = (_smollm_heads() if arch == SMOLLM_HEADS
+           else reduced_config(get_config(arch)))
     if cfg.is_moe:  # capacity dropping differs between grouping modes
         cfg = dataclasses.replace(cfg, capacity_factor=8.0)
     b, s = 2, 48
@@ -81,6 +99,72 @@ def test_prefill_decode_consistency(arch):
     e = np.asarray(full_logits[:, -1])
     rel = np.max(np.abs(a - e)) / (np.max(np.abs(e)) + 1e-9)
     assert rel < 3e-2, f"{arch}: decode inconsistent with prefill ({rel})"
+
+
+@pytest.mark.parametrize("cache", ["full", "empty_slots", "window"])
+@pytest.mark.parametrize("hq,hkv", [(15, 5), (4, 4), (8, 2)])
+def test_attend_decode_matches_repeat_path(hq, hkv, cache):
+    """The grouped decode contraction over a bf16 cache == ``attend``'s
+    repeat-and-upcast path, to f32 rounding: query head h reads KV head
+    h // G, and the same mask, softmax and f32 sums apply."""
+    b, l, d = 3, 96, 64
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(hq * 10 + hkv), 3)
+    # bf16 cache and bf16-valued queries; f32 queries keep the outputs f32
+    q = jax.random.normal(kq, (b, hq, 1, d)).astype(jnp.bfloat16)
+    q = q.astype(jnp.float32)
+    k = jax.random.normal(kk, (b, hkv, l, d)).astype(jnp.bfloat16)
+    v = jax.random.normal(kv, (b, hkv, l, d)).astype(jnp.bfloat16)
+    kv_pos = jnp.arange(l, dtype=jnp.int32)
+    window = 16 if cache == "window" else None
+    if cache == "empty_slots":
+        kv_pos = jnp.where(kv_pos < l - 30, kv_pos, -1)
+    q_pos = jnp.array([int(jnp.max(kv_pos))], jnp.int32)
+    want = attend(q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window)
+    got = attend_decode(q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window)
+    assert got.dtype == want.dtype == jnp.float32
+    rel = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    assert rel <= 1e-5, rel
+
+
+def test_decode_step_holds_no_repeated_or_f32_cache():
+    """One decode step of a one-layer model at SmolLM's head widths lowers
+    with no KV head repeat and no f32 copy of the cache, and counts the
+    grouped path once."""
+    cfg = dataclasses.replace(get_config("smollm-360m"), n_layers=1,
+                              vocab_size=256)
+    b, l = 2, 40
+    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    assert (hq, hkv, d) == (15, 5, 64)
+    params = jax.eval_shape(lambda: T.init_lm(jax.random.PRNGKey(0), cfg))
+    cache = {"groups": jax.eval_shape(
+        lambda: T.init_cache(cfg, b, l, jnp.bfloat16))}
+    token = jax.ShapeDtypeStruct((b, 1), jnp.int32)
+
+    def step(params, cache, token):
+        return T.apply_lm(params, cfg, token, mode="decode", cache=cache,
+                          positions=jnp.array([5], jnp.int32))[:2]
+
+    dispatch.reset_counts()
+    text = jax.jit(step).lower(params, cache, token).as_text()
+    assert dispatch.counts() == {"decode_attention": {"grouped": 1}}
+    g = hq // hkv
+
+    def has(dims, dtype=r"\w+"):
+        lead = r"tensor<(?:\d+x)*"
+        return re.search(lead + "x".join(map(str, dims)) + "x" + dtype + ">",
+                         text) is not None
+
+    assert has((b, hkv, l, d), "bf16")           # the cache itself
+    assert not has((b, hq, l, d))                # KV heads repeated
+    assert not has((b, hkv, g, l, d))            # ... or broadcast
+    assert not has((b, hkv, l, d), "f32")        # an upcast copy
+
+    dispatch.reset_counts()
+    tokens = jax.ShapeDtypeStruct((b, l), jnp.int32)
+    jax.jit(lambda p, t: T.apply_lm(p, cfg, t, mode="train")[0]).lower(
+        params, tokens)
+    assert "decode_attention" not in dispatch.counts()
+    dispatch.reset_counts()
 
 
 def test_sliding_window_ring_cache():
