@@ -367,10 +367,10 @@ def serve_phase(reduced: bool = False, n_req: int = 4, prompt: int = 512,
             f"prefill skipped the kernel: {took}")
 
     xla_cfg = dataclasses.replace(engine.cfg, use_flash=False)
-    flash_logits, _ = jax.jit(make_prefill_step(engine.cfg, prompt + gen + 8))(
-        params, prompts)
-    xla_logits, _ = jax.jit(make_prefill_step(xla_cfg, prompt + gen + 8))(
-        params, prompts)
+    flash_logits = jax.jit(make_prefill_step(engine.cfg, prompt + gen + 8))(
+        params, prompts)[0]
+    xla_logits = jax.jit(make_prefill_step(xla_cfg, prompt + gen + 8))(
+        params, prompts)[0]
     f = np.asarray(flash_logits, np.float32)
     x = np.asarray(xla_logits, np.float32)
     _expect(np.isfinite(f).all() and np.isfinite(x).all(),

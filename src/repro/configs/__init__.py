@@ -1,4 +1,4 @@
-"""Architecture registry: the ten assigned configs + shape cells.
+"""Architecture registry: the assigned configs + shape cells.
 
 ``get_config(arch_id)`` returns the full published config;
 ``reduced_config(cfg)`` shrinks it family-preservingly for CPU smoke tests
@@ -15,6 +15,7 @@ from .jamba_v01_52b import CONFIG as _jamba
 from .mixtral_8x7b import CONFIG as _mixtral
 from .qwen2_moe_a27b import CONFIG as _qwen2moe
 from .deepseek_67b import CONFIG as _deepseek
+from .deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 from .minicpm3_4b import CONFIG as _minicpm3
 from .phi3_mini_38b import CONFIG as _phi3
 from .smollm_360m import CONFIG as _smollm
@@ -24,8 +25,8 @@ from .internvl2_76b import CONFIG as _internvl2
 
 ARCHS: Dict[str, ModelConfig] = {
     c.name: c for c in (
-        _jamba, _mixtral, _qwen2moe, _deepseek, _minicpm3, _phi3, _smollm,
-        _xlstm, _whisper, _internvl2)
+        _jamba, _mixtral, _qwen2moe, _deepseek, _deepseek_v2_lite, _minicpm3,
+        _phi3, _smollm, _xlstm, _whisper, _internvl2)
 }
 
 
@@ -78,9 +79,12 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
     n_heads = n_kv * ratio
     d_head = 16
     d_model = max(n_heads * d_head, 32)
+    n_dense = min(cfg.n_dense_layers, 1)
+    scanned = cfg.n_layers - cfg.n_dense_layers
     updates = dict(
-        n_layers=cfg.group_size * (2 if cfg.n_layers >= 2 * cfg.group_size
-                                   else 1),
+        n_layers=n_dense + cfg.group_size * (2 if scanned >= 2 * cfg.group_size
+                                             else 1),
+        n_dense_layers=n_dense,
         d_model=d_model, n_heads=n_heads, n_kv_heads=n_kv, d_head=d_head,
         d_ff=0 if cfg.d_ff == 0 else 4 * d_model,
         vocab_size=128,
@@ -91,9 +95,12 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
                        experts_per_token=min(cfg.experts_per_token, 2),
                        moe_d_ff=2 * d_model if cfg.moe_d_ff else 0,
                        n_shared_experts=min(cfg.n_shared_experts, 1))
+        if cfg.experts_held is not None:  # hold half of the experts
+            updates.update(experts_held=(0, max(1, updates["n_experts"] // 2)))
     if cfg.attention == "mla":
-        updates.update(q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16,
-                       qk_rope_dim=8, v_head_dim=16)
+        updates.update(q_lora_rank=32 if cfg.q_lora_rank else 0,
+                       kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8,
+                       v_head_dim=16)
     if cfg.window is not None:
         updates.update(window=32)
     if cfg.is_encoder_decoder:

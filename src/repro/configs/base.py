@@ -11,6 +11,17 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type "yarn")."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                     # dense | moe | hybrid | ssm | audio | vlm
@@ -26,8 +37,9 @@ class ModelConfig:
     attention: str = "gqa"          # gqa | mla
     window: Optional[int] = None    # sliding-window size (SWA)
     rope_theta: float = 10_000.0
+    yarn: Optional[Yarn] = None     # YaRN-scaled rope frequencies
     # MLA (DeepSeek/MiniCPM3 style multi-head latent attention)
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0            # 0 → direct query projection
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
@@ -40,6 +52,11 @@ class ModelConfig:
     moe_d_ff: int = 0               # per-expert hidden dim (0 → d_ff)
     moe_every: int = 1              # MoE FFN every k-th layer (Jamba: 2)
     capacity_factor: float = 1.25
+    n_dense_layers: int = 0         # leading dense layers (FFN width d_ff)
+    norm_topk_prob: bool = True     # renormalise the top-k gate weights
+    # routed experts this chip holds: [start, stop) of the n_experts the
+    # router scores; None → all experts, capacity-bounded dispatch
+    experts_held: Optional[Tuple[int, int]] = None
 
     # --- hybrid / SSM --------------------------------------------------------
     # mixer pattern within a layer group; scanned over n_layers/len(pattern)
@@ -67,7 +84,6 @@ class ModelConfig:
     attn_q_chunk: int = 256         # XLA-attention query streaming chunk
     scan_unroll: bool = False       # unroll layer-group scan (roofline runs)
     use_flash: Optional[bool] = None  # None → kernels/dispatch.py rule
-    mla_absorb: bool = False        # absorbed MLA decode (beyond-paper opt)
     kv_quant: bool = False          # int8 KV cache w/ per-vector scales
 
     # -------------------------------------------------------------------------
@@ -84,15 +100,22 @@ class ModelConfig:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def n_held_experts(self) -> int:
+        if self.experts_held is None:
+            return self.n_experts
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
     def group_size(self) -> int:
         return len(self.block_pattern)
 
     @property
     def n_groups(self) -> int:
-        assert self.n_layers % self.group_size == 0, (
-            f"{self.name}: n_layers {self.n_layers} not divisible by "
+        n = self.n_layers - self.n_dense_layers
+        assert n % self.group_size == 0, (
+            f"{self.name}: {n} scanned layers not divisible by "
             f"pattern length {self.group_size}")
-        return self.n_layers // self.group_size
+        return n // self.group_size
 
     @property
     def sub_quadratic(self) -> bool:
@@ -122,8 +145,11 @@ class ModelConfig:
             if kind == "attn":
                 if self.attention == "mla":
                     qd = self.qk_nope_dim + self.qk_rope_dim
-                    total += d * self.q_lora_rank
-                    total += self.q_lora_rank * h * qd
+                    if self.q_lora_rank:
+                        total += d * self.q_lora_rank
+                        total += self.q_lora_rank * h * qd
+                    else:
+                        total += d * h * qd
                     total += d * (self.kv_lora_rank + self.qk_rope_dim)
                     total += self.kv_lora_rank * h * (self.qk_nope_dim
                                                       + self.v_head_dim)
@@ -143,10 +169,11 @@ class ModelConfig:
             # FFN
             if kind in ("mlstm", "slstm") or self.d_ff == 0:
                 continue
-            if self.is_moe and (li % self.moe_every == self.moe_every - 1):
+            if (self.is_moe and li >= self.n_dense_layers
+                    and li % self.moe_every == self.moe_every - 1):
                 f = self.expert_d_ff
                 total += d * self.n_experts  # router
-                total += self.n_experts * 3 * d * f
+                total += self.n_held_experts * 3 * d * f
                 total += self.n_shared_experts * 3 * d * f
             else:
                 total += 3 * d * self.d_ff
@@ -167,7 +194,7 @@ class ModelConfig:
         n_moe_layers = sum(
             1 for li in range(self.n_layers)
             if self.block_pattern[li % self.group_size] not in
-            ("mlstm", "slstm")
+            ("mlstm", "slstm") and li >= self.n_dense_layers
             and li % self.moe_every == self.moe_every - 1)
-        inactive = (self.n_experts - self.experts_per_token)
+        inactive = max(self.n_held_experts - self.experts_per_token, 0)
         return self.param_count() - n_moe_layers * inactive * 3 * d * f

@@ -188,12 +188,8 @@ def _cache_shapes(cfg: ModelConfig, batch: int, cache_len: int):
                            jnp.dtype(cfg.dtype))
     shapes = jax.eval_shape(fn)
     if cfg.is_encoder_decoder:
-        shapes = dict(groups=shapes) if not isinstance(shapes, dict) else \
-            {"groups": shapes}
         shapes["enc_out"] = _sds(
             (batch, cfg.frontend_seq, cfg.d_model), jnp.dtype(cfg.dtype))
-    else:
-        shapes = {"groups": shapes}
     return shapes
 
 
@@ -250,10 +246,8 @@ def lower_cell(arch: str, cell: ShapeCell, mesh: Mesh,
                                (params, specs))
 
         # decode — no remat (nothing to rematerialize for a 1-token step;
-        # the checkpoint wrapper only adds buffer copies); absorbed MLA
-        # scores in latent space instead of re-expanding K/V per token
-        # (measured 7× on minicpm3 decode_32k — EXPERIMENTS §Perf)
-        cfg = dataclasses.replace(cfg, remat=False, mla_absorb=True)
+        # the checkpoint wrapper only adds buffer copies)
+        cfg = dataclasses.replace(cfg, remat=False)
         cache = _cache_shapes(cfg, cell.global_batch, cell.seq_len)
         cache_sh = partition.cache_shardings(cache, cfg, mesh, rules)
         fn = make_decode_fn(cfg)

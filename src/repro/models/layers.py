@@ -17,7 +17,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, Yarn
 from repro.sharding.axes import constrain
 
 Params = Dict[str, jnp.ndarray]
@@ -53,15 +53,51 @@ def rms_norm(params: Params, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # RoPE
 # ---------------------------------------------------------------------------
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(yarn: Yarn, d: int, theta: float):
+    """Inverse frequencies (d/2,) and cos/sin scale of DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``: ``theta^(-2i/d)`` below the
+    correction range of ``beta_fast``, the same over ``factor`` above that
+    of ``beta_slow``, a linear ramp between."""
+    def correction_dim(rotations):
+        return (d * math.log(yarn.original_max_position
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    inv_freq = extra / yarn.factor * ramp + extra * (1.0 - ramp)
+    scale = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return inv_freq, scale
+
+
 def rope(x: jnp.ndarray, positions: jnp.ndarray,
-         theta: float = 10_000.0) -> jnp.ndarray:
-    """x (..., S, D) with D even; positions (..., S) absolute indices."""
+         theta: float = 10_000.0, yarn: Optional[Yarn] = None) -> jnp.ndarray:
+    """x (..., S, D) with D even; positions (..., S) absolute indices.
+    Halves rotate together (``rotate_half``); ``yarn`` scales the
+    frequencies as DeepSeek-V2 does."""
     d = x.shape[-1]
     half = d // 2
-    freqs = jnp.exp(-jnp.arange(half, dtype=jnp.float32)
-                    * (math.log(theta) / half))
+    if yarn is None:
+        freqs = jnp.exp(-jnp.arange(half, dtype=jnp.float32)
+                        * (math.log(theta) / half))
+        scale = 1.0
+    else:
+        freqs, scale = yarn_frequencies(yarn, d, theta)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                               axis=-1)
@@ -372,18 +408,31 @@ def init_mla(rng, cfg: ModelConfig) -> Params:
     d, h = cfg.d_model, cfg.n_heads
     qd = cfg.qk_nope_dim + cfg.qk_rope_dim
     ks = jax.random.split(rng, 6)
-    return {
-        "wdq": _dense_init(ks[0], (d, cfg.q_lora_rank)),
-        "wuq": _dense_init(ks[1], (cfg.q_lora_rank, h * qd)),
+    p = {
         "wdkv": _dense_init(ks[2], (d, cfg.kv_lora_rank + cfg.qk_rope_dim)),
         "wukv": _dense_init(ks[3], (cfg.kv_lora_rank,
                                     h * (cfg.qk_nope_dim + cfg.v_head_dim))),
         "wo": _dense_init(ks[4], (h * cfg.v_head_dim, d),
                           fan_in=h * cfg.v_head_dim),
         "norm": init_rmsnorm(d),
-        "q_norm": init_rmsnorm(cfg.q_lora_rank),
         "kv_norm": init_rmsnorm(cfg.kv_lora_rank),
     }
+    if cfg.q_lora_rank:
+        p["wdq"] = _dense_init(ks[0], (d, cfg.q_lora_rank))
+        p["wuq"] = _dense_init(ks[1], (cfg.q_lora_rank, h * qd))
+        p["q_norm"] = init_rmsnorm(cfg.q_lora_rank)
+    else:
+        p["wq"] = _dense_init(ks[0], (d, h * qd))
+    return p
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(nope + rope)^-0.5``, times ``mscale_all_dim``'s YaRN factor
+    squared where the config scales rope by YaRN (DeepSeek-V2)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.yarn is not None and cfg.yarn.mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
@@ -392,28 +441,34 @@ def mla_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
                   ) -> Tuple[jnp.ndarray, Optional[Params]]:
     """Latent attention: KV compressed to ``kv_lora_rank`` + shared RoPE key.
 
-    Cache stores only the latent ``c_kv`` and rope key — the paper-exact
-    memory win.  Baseline decode re-expands K/V from the latent each step;
-    ``cfg.mla_absorb`` switches to the absorbed formulation (beyond-paper
-    optimization recorded in EXPERIMENTS §Perf).
+    The cache stores only the latent ``c_kv`` and the rope key.  Train and
+    prefill expand K and V from the latent; a decode step scores and
+    contracts against the latent cache itself (``attend_latent``).
     """
     b, s, d = x.shape
     h = cfg.n_heads
     nope, rdim, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    r = cfg.kv_lora_rank
     dt = x.dtype
     xn = rms_norm(params["norm"], x, cfg.norm_eps)
 
-    cq = rms_norm(params["q_norm"], xn @ params["wdq"].astype(dt), cfg.norm_eps)
-    q = (cq @ params["wuq"].astype(dt)).reshape(b, s, h, nope + rdim)
-    q = q.transpose(0, 2, 1, 3)
+    if cfg.q_lora_rank:
+        cq = rms_norm(params["q_norm"], xn @ params["wdq"].astype(dt),
+                      cfg.norm_eps)
+        q = cq @ params["wuq"].astype(dt)
+    else:
+        q = xn @ params["wq"].astype(dt)
+    q = q.reshape(b, s, h, nope + rdim).transpose(0, 2, 1, 3)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = rope(q_rope, positions[None, None, :], cfg.rope_theta)
+    q_rope = rope(q_rope, positions[None, None, :], cfg.rope_theta, cfg.yarn)
 
     dkv = xn @ params["wdkv"].astype(dt)            # (B,S,kv_lora + rdim)
-    c_kv = rms_norm(params["kv_norm"], dkv[..., :cfg.kv_lora_rank],
-                    cfg.norm_eps)
-    k_rope = rope(dkv[..., None, cfg.kv_lora_rank:].transpose(0, 2, 1, 3),
-                  positions[None, None, :], cfg.rope_theta)  # (B,1,S,rdim)
+    c_kv = rms_norm(params["kv_norm"], dkv[..., :r], cfg.norm_eps)
+    k_rope = rope(dkv[..., None, r:].transpose(0, 2, 1, 3),
+                  positions[None, None, :], cfg.rope_theta,
+                  cfg.yarn)                          # (B,1,S,rdim)
+    scale = mla_softmax_scale(cfg)
+    wukv = params["wukv"].astype(dt)
 
     new_cache = None
     if mode == "decode":
@@ -425,10 +480,10 @@ def mla_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
             cpos, positions.astype(jnp.int32), slot, axis=0)
         new_cache = {"c_kv": cc, "k_rope": cr, "pos": cpos,
                      "cursor": jnp.asarray(slot + s, jnp.int32)}
-        c_kv_full, k_rope_full, kpos = cc, cr, cpos
+        o = attend_latent(q_nope, q_rope, cc, cr[:, 0],
+                          wukv.reshape(r, h, nope + vdim), q_pos=positions,
+                          kv_pos=cpos, sm_scale=scale)
     else:
-        c_kv_full, k_rope_full = c_kv, k_rope
-        kpos = positions
         if mode == "prefill":
             clen = cache_len or s
             pad = clen - s
@@ -438,36 +493,62 @@ def mla_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
                 "pos": jnp.pad(positions.astype(jnp.int32), (0, pad),
                                constant_values=-1),
                 "cursor": jnp.asarray(s, jnp.int32)}
-
-    scale = (nope + rdim) ** -0.5
-    if cfg.mla_absorb and mode == "decode":
-        # absorbed: score in latent space — never re-expand K
-        wukv = params["wukv"].astype(dt).reshape(cfg.kv_lora_rank, h,
-                                                 nope + vdim)
-        wuk = wukv[..., :nope]                      # (r, h, nope)
-        q_lat = jnp.einsum("bhsn,rhn->bhsr", q_nope, wuk)
-        s_nope = jnp.einsum("bhsr,blr->bhsl", q_lat, c_kv_full)
-        s_rope = jnp.einsum("bhsr,blr->bhsl", q_rope, k_rope_full[:, 0])
-        scores = (s_nope + s_rope).astype(jnp.float32) * scale
-        allow = _mask_for_chunk(positions, kpos, True, None)
-        scores = jnp.where(allow[None, None], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1)
-        wuv = wukv[..., nope:]                      # (r, h, vdim)
-        o_lat = jnp.einsum("bhsl,blr->bhsr", p.astype(dt), c_kv_full)
-        o = jnp.einsum("bhsr,rhv->bhsv", o_lat, wuv)
-    else:
-        # baseline: expand K/V from latent (paper-faithful reference path)
-        kv = (c_kv_full @ params["wukv"].astype(dt)).reshape(
-            b, -1, h, nope + vdim).transpose(0, 2, 1, 3)
+        # expand K/V from the latent
+        kv = (c_kv @ wukv).reshape(b, s, h, nope + vdim).transpose(0, 2, 1, 3)
         k_nope, v = kv[..., :nope], kv[..., nope:]
-        k_r = jnp.broadcast_to(k_rope_full, (b, h) + k_rope_full.shape[2:])
+        k_r = jnp.broadcast_to(k_rope, (b, h) + k_rope.shape[2:])
         k = jnp.concatenate([k_nope, k_r], axis=-1)
         qc = jnp.concatenate([q_nope, q_rope], axis=-1)
-        o = attend(qc, k, v, q_pos=positions, kv_pos=kpos, causal=True,
+        o = attend(qc, k, v, q_pos=positions, kv_pos=positions, causal=True,
                    sm_scale=scale, q_chunk=cfg.attn_q_chunk)
 
     y = o.transpose(0, 2, 1, 3).reshape(b, s, h * vdim) @ params["wo"].astype(dt)
     return constrain(y, "batch", "seq", "embed"), new_cache
+
+
+def attend_latent(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
+                  c_kv: jnp.ndarray, k_rope: jnp.ndarray, wukv: jnp.ndarray,
+                  *, q_pos: jnp.ndarray, kv_pos: jnp.ndarray,
+                  sm_scale: float) -> jnp.ndarray:
+    """A decode step's MLA over the latent cache, never expanding K or V.
+
+    q_nope (B,H,S,nope), q_rope (B,H,S,rope); c_kv (B,L,r) and k_rope
+    (B,L,rope) as the cache stores them; wukv (r,H,nope+v) the latent's
+    up-projection.  ``q_nope`` is absorbed into the latent through the
+    key half of ``wukv``; scores contract it with ``c_kv`` and ``q_rope``
+    with ``k_rope``; the probabilities contract with ``c_kv`` into a
+    latent output that the value half of ``wukv`` expands.  Every
+    contraction reads the cache once in its own dtype and accumulates in
+    f32; the f32 probabilities enter as exact pieces in the cache's dtype
+    (``_exact_pieces``), as in ``attend_decode``.
+    """
+    from repro.kernels import dispatch
+
+    dispatch.record("decode_attention", "latent")
+    b, h, s, nope = q_nope.shape
+    dt = c_kv.dtype
+    with jax.named_scope("mla.latent"):
+        q_lat = jnp.einsum("bhsn,rhn->bhsr", q_nope, wukv[..., :nope],
+                           preferred_element_type=jnp.float32).astype(dt)
+        scores = (jnp.einsum("bhsr,blr->bhsl", q_lat, c_kv,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bhsd,bld->bhsl", q_rope.astype(dt), k_rope,
+                               preferred_element_type=jnp.float32)) * sm_scale
+        allow = _mask_for_chunk(q_pos, kv_pos, True, None)[None, None]
+        scores = jnp.where(allow, scores, -1e30)
+        m = jnp.max(scores, axis=-1, keepdims=True)
+        p = jnp.where(allow, jnp.exp(scores - m), 0.0)
+        denom = jnp.sum(p, axis=-1, keepdims=True)
+        pieces = _exact_pieces(p, dt)                   # (n,B,H,S,L)
+        n = pieces.shape[0]
+        pieces = jnp.moveaxis(pieces, 0, 1).reshape(b, n * h, s, -1)
+        o_lat = jnp.einsum("bhsl,blr->bhsr", pieces, c_kv,
+                           preferred_element_type=jnp.float32)
+        o_lat = o_lat.reshape(b, n, h, s, -1).sum(axis=1)
+        o_lat = (o_lat / jnp.maximum(denom, 1e-30)).astype(dt)
+        o = jnp.einsum("bhsr,rhv->bhsv", o_lat, wukv[..., nope:],
+                       preferred_element_type=jnp.float32)
+    return o.astype(q_nope.dtype)
 
 
 # ---------------------------------------------------------------------------

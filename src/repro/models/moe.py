@@ -17,6 +17,11 @@ through sharding constraints:
 Overflowing tokens beyond per-group capacity are *dropped* (their combine
 weight is zero) and counted — the same overflow contract as the table
 shuffle; the trainer monitors the dropped fraction.
+
+A config that names the experts this chip holds (``experts_held``) takes
+the held layer instead (:func:`held_moe_ffn`): it routes over all
+``n_experts``, computes only its own experts, for every token routed to
+them, and drops none.
 """
 from __future__ import annotations
 
@@ -29,7 +34,14 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.sharding.axes import constrain
 
-from .layers import Params, _dense_init, init_rmsnorm, rms_norm
+from .layers import Params, _dense_init, init_rmsnorm, mlp, rms_norm
+
+#: integer counters of the held layer, under its metrics' ``counters``
+#: and summed over layers like the metrics: token-expert pairs routed to
+#: held experts, and those of them left uncomputed
+HELD_COUNTERS = ("moe_held_pairs", "moe_dropped_pairs")
+#: tokens the held layer routes and computes at a time
+HELD_CHUNK = 16384
 
 
 def padded_experts(cfg: ModelConfig, model_axis: int = 16) -> int:
@@ -42,11 +54,14 @@ def padded_experts(cfg: ModelConfig, model_axis: int = 16) -> int:
 
 def init_moe(rng, cfg: ModelConfig) -> Params:
     d, f = cfg.d_model, cfg.expert_d_ff
-    e = padded_experts(cfg)
+    if cfg.experts_held is None:
+        e = e_router = padded_experts(cfg)
+    else:   # the router scores every expert; only the held ones live here
+        e, e_router = cfg.n_held_experts, cfg.n_experts
     ks = jax.random.split(rng, 5)
     p = {
         "norm": init_rmsnorm(d),
-        "router": _dense_init(ks[0], (d, e)),
+        "router": _dense_init(ks[0], (d, e_router)),
         "w_gate": _dense_init(ks[1], (e, d, f)),
         "w_in": _dense_init(ks[2], (e, d, f)),
         "w_out": _dense_init(ks[3], (e, f, d), fan_in=f),
@@ -77,8 +92,11 @@ def moe_ffn(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     which is exactly the operator-mismatch anti-pattern the paper calls out
     (§IV: AllReduce-via-GroupBy).  The shard_map path expresses the shuffle
     directly: local pack → local expert compute on the device's expert
-    slice → ONE psum combine.  See EXPERIMENTS.md §Perf.
+    slice → ONE psum combine.  A config that names the experts held here
+    takes :func:`held_moe_ffn`.
     """
+    if cfg.experts_held is not None:
+        return held_moe_ffn(params, cfg, x)
     from repro.sharding import axes as axes_mod
     mesh = axes_mod.current_mesh()
     if mesh is not None and "model" in mesh.axis_names:
@@ -98,7 +116,8 @@ def _routing(params: Params, cfg: ModelConfig, xn: jnp.ndarray):
         logits = jnp.where(pad_mask, -1e30, logits)
     gates = jax.nn.softmax(logits, axis=-1)
     top_g, top_i = jax.lax.top_k(gates, k)
-    top_g = top_g / jnp.maximum(jnp.sum(top_g, -1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        top_g = top_g / jnp.maximum(jnp.sum(top_g, -1, keepdims=True), 1e-9)
     me = jnp.mean(gates.reshape(-1, e), axis=0)
     ce = jnp.mean(
         jnp.sum(jax.nn.one_hot(top_i.reshape(-1, k), e), axis=1), axis=0) / k
@@ -268,7 +287,8 @@ def _moe_ffn_einsum(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         logits = jnp.where(pad_mask, -1e30, logits)
     gates = jax.nn.softmax(logits, axis=-1)                    # (B,S,E)
     top_g, top_i = jax.lax.top_k(gates, k)                     # (B,S,k)
-    top_g = top_g / jnp.maximum(jnp.sum(top_g, -1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        top_g = top_g / jnp.maximum(jnp.sum(top_g, -1, keepdims=True), 1e-9)
 
     # load-balance aux loss (Switch) + router z-loss
     me = jnp.mean(gates.reshape(-1, e), axis=0)
@@ -341,3 +361,113 @@ def _moe_ffn_einsum(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     metrics = {"moe_aux_loss": aux, "router_z_loss": router_z,
                "moe_dropped_frac": dropped}
     return y, metrics
+
+
+def _held_rows(tokens: int, k: int, n_held: int) -> int:
+    """Rows of the held layer's sorted buffer: every pair a chunk of
+    ``tokens`` can route to held experts (at most ``min(k, n_held)`` per
+    token, its top-k being distinct)."""
+    return tokens * min(k, n_held)
+
+
+def held_moe_ffn(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """The expert layer of one chip's share: x (B, S, D) → (y, metrics).
+
+    Routes every token over all ``cfg.n_experts`` (softmax in f32, top-k,
+    renormalised only where ``cfg.norm_topk_prob``), computes the held
+    experts
+    ``cfg.experts_held`` for the tokens routed to them, and adds the
+    shared experts.  The pairs of held experts are sorted by expert and
+    fed to a grouped matmul (``jax.lax.ragged_dot``) over a buffer that
+    has room for every pair the chunk can route here, so none is dropped;
+    tokens go through in chunks of ``HELD_CHUNK``.  What the other
+    experts would add is not computed here.
+    """
+    from repro.kernels import dispatch
+
+    dispatch.record("moe", "held_dropless")
+    b, s, d = x.shape
+    dt = x.dtype
+    t = b * s
+    xn3 = rms_norm(params["norm"], x, cfg.norm_eps)
+    xn = xn3.reshape(t, d)
+    chunk = min(t, HELD_CHUNK)
+    n_chunks = -(-t // chunk)
+    pad = n_chunks * chunk - t
+    valid = jnp.arange(n_chunks * chunk) < t
+    xc = jnp.pad(xn, ((0, pad), (0, 0))).reshape(n_chunks, chunk, d)
+    vc = valid.reshape(n_chunks, chunk)
+
+    y, aux, z, held, dropped = jax.lax.map(
+        lambda args: _held_chunk(params, cfg, *args), (xc, vc))
+    aux, z = jnp.mean(aux), jnp.mean(z)
+    held, dropped = jnp.sum(held), jnp.sum(dropped)
+    y = y.reshape(n_chunks * chunk, d)[:t]
+    if "shared" in params:
+        y = y + mlp(params["shared"], cfg, xn3, skip_norm=True).reshape(
+            t, d).astype(jnp.float32)
+    y = constrain(y.astype(dt).reshape(b, s, d), "batch", "seq", "embed")
+    return y, {"moe_aux_loss": aux, "router_z_loss": z,
+               "moe_dropped_frac": dropped / jnp.maximum(held, 1),
+               "counters": {"moe_held_pairs": held,
+                            "moe_dropped_pairs": dropped}}
+
+
+def _held_chunk(params: Params, cfg: ModelConfig, xn, valid):
+    """One chunk of tokens through the held layer: xn (T, D), valid (T,)
+    → (routed part of y in f32, aux loss, z loss, held pairs, dropped
+    pairs)."""
+    t, d = xn.shape
+    dt = xn.dtype
+    e, k = cfg.n_experts, cfg.experts_per_token
+    e0, e1 = cfg.experts_held
+    n_held = e1 - e0
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(xn.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        gates = jax.nn.softmax(logits, axis=-1)                  # (T, E)
+        top_g, top_i = jax.lax.top_k(gates, k)                   # (T, k)
+        if cfg.norm_topk_prob:
+            top_g = top_g / jnp.maximum(jnp.sum(top_g, -1, keepdims=True),
+                                        1e-20)
+        w = jnp.where(valid[:, None], top_g, 0.0)
+        me = jnp.sum(gates * valid[:, None], 0) / jnp.maximum(
+            jnp.sum(valid), 1)
+        ce = jnp.sum(jax.nn.one_hot(top_i, e) * valid[:, None, None],
+                     (0, 1)) / jnp.maximum(jnp.sum(valid) * k, 1)
+        aux = jnp.sum(me * ce) * e
+        z = jnp.sum(jnp.square(jax.nn.logsumexp(logits, -1)) * valid) \
+            / jnp.maximum(jnp.sum(valid), 1)
+        # held pairs first, by expert; the rest after them
+        flat = top_i.reshape(-1)
+        mine = (flat >= e0) & (flat < e1) & jnp.repeat(valid, k)
+        local = jnp.where(mine, flat - e0, n_held)
+        order = jnp.argsort(local, stable=True)
+        rows = _held_rows(t, k, n_held)
+        sizes = jnp.sum(jax.nn.one_hot(local, n_held, dtype=jnp.int32), 0)
+        held = jnp.sum(sizes)
+        # groups past the buffer's end would be cut short: count them
+        ends = jnp.minimum(jnp.cumsum(sizes), rows)
+        kept = jnp.diff(ends, prepend=0)
+        dropped = held - jnp.sum(kept)
+    with jax.named_scope("moe.experts"):
+        src = order[:rows]
+        xs = xn[src // k]                                        # (rows, D)
+        g = jax.lax.ragged_dot(xs, params["w_gate"].astype(dt), kept)
+        u = jax.lax.ragged_dot(xs, params["w_in"].astype(dt), kept)
+        out = jax.lax.ragged_dot(jax.nn.silu(g) * u,
+                                 params["w_out"].astype(dt), kept)
+        computed = jnp.arange(rows) < ends[-1]
+        out = jnp.where(computed[:, None], out, 0.0)
+    with jax.named_scope("moe.combine"):
+        # each pair's row in the sorted buffer, or none
+        where = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        hit = mine & (where < ends[-1])
+        pair_out = jnp.where(hit[:, None],
+                             out[jnp.minimum(where, rows - 1)], 0.0)
+        y = jnp.sum(pair_out.reshape(t, k, d).astype(jnp.float32)
+                    * w[..., None], axis=1)
+    return y, aux, z, held, dropped

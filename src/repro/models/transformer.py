@@ -31,6 +31,24 @@ from .layers import (Params, _dense_init, gqa_attention, init_attention,
 _ZERO_METRICS = ("moe_aux_loss", "router_z_loss", "moe_dropped_frac")
 
 
+def zero_metrics(cfg: ModelConfig) -> Params:
+    """The aux metrics ``apply_lm`` returns for ``cfg``, zero: the MoE
+    losses, and under ``counters`` the integer counters of a held expert
+    layer."""
+    out = {k: jnp.zeros((), jnp.float32) for k in _ZERO_METRICS}
+    if cfg.is_moe and cfg.experts_held is not None:
+        out["counters"] = {k: jnp.zeros((), jnp.int32)
+                           for k in moe_mod.HELD_COUNTERS}
+    return out
+
+
+def dense_layer_config(cfg: ModelConfig) -> ModelConfig:
+    """The config of a leading dense layer: the model's attention with a
+    SwiGLU of width ``d_ff`` in place of the experts."""
+    import dataclasses
+    return dataclasses.replace(cfg, n_experts=0, n_dense_layers=0)
+
+
 def _layer_has_moe(cfg: ModelConfig, i: int, kind: str) -> bool:
     if not cfg.is_moe or cfg.d_ff == 0 or kind in ("mlstm", "slstm"):
         return False
@@ -71,7 +89,7 @@ def init_layer(rng, cfg: ModelConfig, kind: str, i: int,
 def apply_layer(p: Params, cfg: ModelConfig, x, kind: str, i: int, *,
                 mode: str, cache, positions, enc_out=None, causal=True,
                 cache_len: int = 0):
-    metrics = {k: jnp.zeros((), jnp.float32) for k in _ZERO_METRICS}
+    metrics = zero_metrics(cfg)
     mix_cache = cache["mixer"] if cache is not None else None
     if kind == "attn":
         fn = mla_attention if cfg.attention == "mla" else gqa_attention
@@ -99,7 +117,7 @@ def apply_layer(p: Params, cfg: ModelConfig, x, kind: str, i: int, *,
         if _layer_has_moe(cfg, i, kind):
             dff, m = moe_mod.moe_ffn(p["ffn"], cfg, x)
             for k, v in m.items():
-                metrics[k] = metrics[k] + v
+                metrics[k] = jax.tree.map(jnp.add, metrics[k], v)
         else:
             dff = mlp(p["ffn"], cfg, x)
         x = x + dff
@@ -121,10 +139,12 @@ def init_stack(rng, cfg: ModelConfig, cross: bool = False) -> Params:
 
 
 def init_group_cache(cfg: ModelConfig, batch: int, cache_len: int,
-                     dtype) -> Params:
-    """Zero decode cache for one group (stacked by caller)."""
+                     dtype, pattern: Optional[Tuple[str, ...]] = None
+                     ) -> Params:
+    """Zero decode cache for one group (stacked by caller), or for the
+    layers of ``pattern``."""
     out = {}
-    for i, kind in enumerate(cfg.block_pattern):
+    for i, kind in enumerate(pattern or cfg.block_pattern):
         if kind == "attn":
             if cfg.attention == "mla":
                 mix = {
@@ -158,9 +178,17 @@ def init_group_cache(cfg: ModelConfig, batch: int, cache_len: int,
 
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype) -> Params:
+    """Zero decode cache as ``apply_lm`` takes it: the scanned groups'
+    stacked on a leading axis, and the leading dense layers' one entry
+    each."""
     one = init_group_cache(cfg, batch, cache_len, dtype)
-    return jax.tree.map(
-        lambda a: jnp.broadcast_to(a[None], (cfg.n_groups,) + a.shape), one)
+    cache = {"groups": jax.tree.map(
+        lambda a: jnp.broadcast_to(a[None], (cfg.n_groups,) + a.shape), one)}
+    if cfg.n_dense_layers:
+        cache["dense"] = init_group_cache(
+            cfg, batch, cache_len, dtype,
+            pattern=("attn",) * cfg.n_dense_layers)
+    return cache
 
 
 @jax.custom_vjp
@@ -200,7 +228,7 @@ def apply_stack(stacked: Params, cfg: ModelConfig, x, *, mode: str,
                 positions=positions, enc_out=enc_out, causal=causal,
                 cache_len=cache_len)
             for k, v in m.items():
-                aux[k] = aux[k] + v
+                aux[k] = jax.tree.map(jnp.add, aux[k], v)
             if nc is not None:
                 new_caches[f"layer_{i}"] = nc
         ys = new_caches if new_caches else None
@@ -209,7 +237,7 @@ def apply_stack(stacked: Params, cfg: ModelConfig, x, *, mode: str,
     if cfg.remat:
         body = jax.checkpoint(body)
 
-    aux0 = {k: jnp.zeros((), jnp.float32) for k in _ZERO_METRICS}
+    aux0 = zero_metrics(cfg)
     xs = stacked if caches is None else (stacked, caches)
     (x, aux), new_caches = jax.lax.scan(
         body, (x, aux0), xs, unroll=True if cfg.scan_unroll else 1)
@@ -227,6 +255,11 @@ def init_lm(rng, cfg: ModelConfig) -> Params:
         "final_norm": init_rmsnorm(cfg.d_model),
         "decoder": init_stack(ks[1], cfg, cross=cfg.is_encoder_decoder),
     }
+    if cfg.n_dense_layers:
+        dcfg = dense_layer_config(cfg)
+        kd = jax.random.split(ks[4], cfg.n_dense_layers)
+        p["dense"] = {f"layer_{i}": init_layer(kd[i], dcfg, "attn", 0)
+                      for i in range(cfg.n_dense_layers)}
     if not cfg.tie_embeddings:
         p["lm_head"] = _dense_init(ks[2], (cfg.d_model, cfg.vocab_size))
     if cfg.is_encoder_decoder:
@@ -265,7 +298,9 @@ def apply_lm(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
     ``arange(S)`` (train/prefill) and must be given for decode.
     ``last_logit_only``: serving prefill needs logits for the final
     position only — skipping the (B,S,V) head matmul + its TP reduction is
-    a large collective/memory win (EXPERIMENTS.md §Perf).
+    a large collective/memory win.  Leading dense layers
+    (``cfg.n_dense_layers``) run before the scanned groups, each with its
+    own cache entry under ``cache["dense"]``.
     """
     dtype = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
@@ -286,6 +321,18 @@ def apply_lm(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)
 
+    new_dense = {}
+    if cfg.n_dense_layers:
+        dcfg = dense_layer_config(cfg)
+        for i in range(cfg.n_dense_layers):
+            name = f"layer_{i}"
+            x, nc, _ = apply_layer(
+                params["dense"][name], dcfg, x, "attn", 0, mode=mode,
+                cache=cache["dense"][name] if cache is not None else None,
+                positions=positions, cache_len=cache_len)
+            if nc is not None:
+                new_dense[name] = nc
+
     groups_cache = cache["groups"] if cache is not None else None
     x, new_groups, aux = apply_stack(
         params["decoder"], cfg, x, mode=mode, caches=groups_cache,
@@ -302,6 +349,8 @@ def apply_lm(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
     new_cache = None
     if mode in ("prefill", "decode") and new_groups is not None:
         new_cache = {"groups": new_groups}
+        if new_dense:
+            new_cache["dense"] = new_dense
         if cfg.is_encoder_decoder:
             new_cache["enc_out"] = enc_out
     return logits.astype(jnp.float32), new_cache, aux

@@ -140,9 +140,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig):
 
             zero_g = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-            zero_m = {k: jnp.zeros((), jnp.float32) for k in
-                      ("loss", "accuracy", "moe_aux_loss", "router_z_loss",
-                       "moe_dropped_frac")}
+            zero_m = {"loss": jnp.zeros((), jnp.float32),
+                      "accuracy": jnp.zeros((), jnp.float32),
+                      **T.zero_metrics(cfg)}
             (grads, metrics), _ = jax.lax.scan(
                 body, (zero_g, zero_m), micro_batches)
             grads = jax.tree.map(lambda g: g / m, grads)
