@@ -11,6 +11,7 @@ and what trains on CPU; on TPU the Pallas flash kernel
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -191,10 +192,24 @@ def _exact_pieces(x: jnp.ndarray, dtype) -> jnp.ndarray:
     return jnp.stack(pieces)
 
 
+def _joint_softmax(scores):
+    """Unnormalised probabilities of score blocks (each a ``(scores,
+    allow)`` pair) that share one softmax: one max over every block's
+    allowed scores is subtracted before the exp, masked entries are 0;
+    and the denominator, their sum over every block."""
+    scores = [(jnp.where(allow, sc, -1e30), allow) for sc, allow in scores]
+    m = functools.reduce(jnp.maximum, [jnp.max(sc, axis=-1, keepdims=True)
+                                       for sc, _ in scores])
+    ps = [jnp.where(allow, jnp.exp(sc - m), 0.0) for sc, allow in scores]
+    return ps, functools.reduce(jnp.add, [jnp.sum(p, axis=-1, keepdims=True)
+                                          for p in ps])
+
+
 def attend_decode(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, *,
                   q_pos: jnp.ndarray, kv_pos: jnp.ndarray,
-                  causal: bool = True,
-                  window: Optional[int] = None) -> jnp.ndarray:
+                  causal: bool = True, window: Optional[int] = None,
+                  new: Optional[Tuple[jnp.ndarray, ...]] = None
+                  ) -> jnp.ndarray:
     """A decode step's masked attention over a KV cache, grouped by KV head.
 
     q (B,Hq,S,D); ck, cv (B,Hkv,L,D/Dv) as the cache stores them; q_pos
@@ -210,6 +225,10 @@ def attend_decode(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, *,
         axis so that one contraction reads cv once; the pieces' f32
         partial sums are then added.
 
+    ``new`` = (k, v, pos) are the step's own entries, which the cache does
+    not hold yet: their scores enter the same max-subtract, exp and sum as
+    the cache's, and their values the same output.
+
     A single query over the cache needs no query chunks and no
     rematerialisation, and serving shards no head axis, so nothing asks
     for ``attend``'s repeat here.
@@ -218,25 +237,65 @@ def attend_decode(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray, *,
 
     dispatch.record("decode_attention", "grouped")
     b, hq, s, d = q.shape
-    hkv, l = ck.shape[1], ck.shape[2]
-    dv = cv.shape[-1]
+    hkv = ck.shape[1]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, s, d)
-    scores = jnp.einsum("bkgsd,bkld->bkgsl", qg, ck,
-                        preferred_element_type=jnp.float32) * d ** -0.5
-    allow = _mask_for_chunk(q_pos, kv_pos, causal, window)[None, None, None]
-    scores = jnp.where(allow, scores, -1e30)
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    p = jnp.where(allow, jnp.exp(scores - m), 0.0)
-    denom = jnp.sum(p, axis=-1, keepdims=True)
-    pieces = _exact_pieces(p, cv.dtype)                 # (n,B,Hkv,G,S,L)
-    n = pieces.shape[0]
-    pieces = jnp.moveaxis(pieces, 0, 2).reshape(b, hkv, n * g, s, l)
-    o = jnp.einsum("bkgsl,bkld->bkgsd", pieces, cv,
-                   preferred_element_type=jnp.float32)
-    o = o.reshape(b, hkv, n, g, s, dv).sum(axis=2)
-    o = o / jnp.maximum(denom, 1e-30)
-    return o.reshape(b, hq, s, dv).astype(q.dtype)
+    blocks = [(ck, cv, kv_pos)] + ([new] if new is not None else [])
+    scores = [(jnp.einsum("bkgsd,bkld->bkgsl", qg, k,
+                          preferred_element_type=jnp.float32) * d ** -0.5,
+               _mask_for_chunk(q_pos, pos, causal, window)[None, None, None])
+              for k, _, pos in blocks]
+    ps, denom = _joint_softmax(scores)
+    outs = []
+    for (_, v, _), p in zip(blocks, ps):
+        pieces = _exact_pieces(p, v.dtype)             # (n,B,Hkv,G,S,L)
+        n = pieces.shape[0]
+        pieces = jnp.moveaxis(pieces, 0, 2).reshape(b, hkv, n * g, s, -1)
+        o = jnp.einsum("bkgsl,bkld->bkgsd", pieces, v,
+                       preferred_element_type=jnp.float32)
+        outs.append(o.reshape(b, hkv, n, g, s, -1).sum(axis=2))
+    o = functools.reduce(jnp.add, outs) / jnp.maximum(denom, 1e-30)
+    return o.reshape(b, hq, s, -1).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# decode caches: a step's write
+# ---------------------------------------------------------------------------
+#: the slot axis of each cache leaf that holds one entry per position,
+#: counted from the end, so that a layer stack's cache (one more leading
+#: axis) has the same; every other leaf (a cursor, a recurrent state) is
+#: written whole
+SLOT_AXIS = {"k": -2, "v": -2, "k_s": -2, "v_s": -2, "c_kv": -2,
+             "k_rope": -2, "pos": -1}
+
+
+def write_slot(cache: Params, entries: Params) -> Params:
+    """``cache`` with a decode step's ``entries`` written in.
+
+    ``cache`` is one layer's cache, or a layer stack's with the entries
+    of every group stacked alike.  An entry under a key of ``SLOT_AXIS``
+    holds the step's positions and goes in at the cache's cursor as it
+    stood before the step; any other entry replaces its leaf.  Each entry
+    is one ``dynamic_update_slice`` of the stored array, which XLA makes
+    in place where the array is donated: no other slot is read or copied.
+    Every layer of a model steps in lockstep, so the first layer's cursor
+    is every layer's slot.
+    """
+    out = dict(cache)
+    slot = cache["cursor"].reshape(-1)[0] if "cursor" in cache else None
+    for key, new in entries.items():
+        start = [0] * new.ndim
+        if key in SLOT_AXIS:
+            start[SLOT_AXIS[key]] = slot
+        out[key] = jax.lax.dynamic_update_slice(cache[key], new, start)
+    return out
+
+
+def _unwritten(kv_pos: jnp.ndarray, slot, s: int) -> jnp.ndarray:
+    """``kv_pos`` with the ``s`` slots from ``slot`` on marked empty: a
+    decode step's own entries take them."""
+    l = kv_pos.shape[0]
+    return jnp.where((jnp.arange(l) - slot) % l < s, -1, kv_pos)
 
 
 # ---------------------------------------------------------------------------
@@ -260,7 +319,10 @@ def gqa_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
                   kv_source: Optional[jnp.ndarray] = None,
                   causal: bool = True, cache_len: int = 0,
                   ) -> Tuple[jnp.ndarray, Optional[Params]]:
-    """Pre-norm GQA attention. Returns (residual_delta, new_cache)."""
+    """Pre-norm GQA attention. Returns (residual_delta, new_cache).
+
+    A decode step's new cache is the step's entries alone, for the caller
+    to write into ``cache`` (``write_slot``)."""
     b, s, d = x.shape
     h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = x.dtype
@@ -282,37 +344,27 @@ def gqa_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
 
     new_cache = None
     if mode == "decode" and not is_cross:
-        # append to ring/linear cache and attend over it
-        cpos = cache["pos"]
-        slot = cache["cursor"]  # scalar int32 write index
+        # attend over the ring/linear cache and the step's own entries;
+        # the entries are the layer's new cache, which the caller writes
+        # in over the slots they take (``write_slot``)
+        slot = cache["cursor"]
+        ck, cv = cache["k"], cache["v"]
         if cfg.kv_quant:
-            kq, ks = kv_quantize(k)
-            vq, vs = kv_quantize(v)
-            ckq = jax.lax.dynamic_update_slice_in_dim(cache["k"], kq, slot,
-                                                      axis=2)
-            cks = jax.lax.dynamic_update_slice_in_dim(cache["k_s"], ks,
-                                                      slot, axis=2)
-            cvq = jax.lax.dynamic_update_slice_in_dim(cache["v"], vq, slot,
-                                                      axis=2)
-            cvs = jax.lax.dynamic_update_slice_in_dim(cache["v_s"], vs,
-                                                      slot, axis=2)
-            ck = kv_dequantize(ckq, cks, dt)
-            cv = kv_dequantize(cvq, cvs, dt)
-            stored = {"k": ckq, "k_s": cks, "v": cvq, "v_s": cvs}
+            (kq, ks), (vq, vs) = kv_quantize(k), kv_quantize(v)
+            new_cache = {"k": kq, "k_s": ks, "v": vq, "v_s": vs}
+            ck = kv_dequantize(ck, cache["k_s"], dt)
+            cv = kv_dequantize(cv, cache["v_s"], dt)
+            k, v = kv_dequantize(kq, ks, dt), kv_dequantize(vq, vs, dt)
         else:
-            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot,
-                                                     axis=2)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot,
-                                                     axis=2)
-            stored = {"k": ck, "v": cv}
-        cpos = jax.lax.dynamic_update_slice_in_dim(
-            cpos, positions.astype(jnp.int32), slot, axis=0)
+            new_cache = {"k": k, "v": v}
         cache_len = ck.shape[2]
         cursor = (slot + s) % cache_len if cfg.window else slot + s
-        new_cache = {**stored, "pos": cpos,
-                     "cursor": jnp.asarray(cursor, jnp.int32)}
-        o = attend_decode(q, ck, cv, q_pos=positions, kv_pos=cpos,
-                          causal=causal, window=cfg.window)
+        new_cache.update(pos=positions.astype(jnp.int32),
+                         cursor=jnp.asarray(cursor, jnp.int32))
+        o = attend_decode(q, ck, cv, q_pos=positions,
+                          kv_pos=_unwritten(cache["pos"], slot, s),
+                          causal=causal, window=cfg.window,
+                          new=(k, v, positions))
     else:
         if is_cross:
             kv_pos = jnp.arange(k.shape[2], dtype=jnp.int32)
@@ -443,7 +495,8 @@ def mla_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
 
     The cache stores only the latent ``c_kv`` and the rope key.  Train and
     prefill expand K and V from the latent; a decode step scores and
-    contracts against the latent cache itself (``attend_latent``).
+    contracts against the latent cache itself and its own entries
+    (``attend_latent``), which are its new cache, as in ``gqa_attention``.
     """
     b, s, d = x.shape
     h = cfg.n_heads
@@ -472,17 +525,14 @@ def mla_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
 
     new_cache = None
     if mode == "decode":
-        cc, cr, cpos = cache["c_kv"], cache["k_rope"], cache["pos"]
         slot = cache["cursor"]
-        cc = jax.lax.dynamic_update_slice_in_dim(cc, c_kv, slot, axis=1)
-        cr = jax.lax.dynamic_update_slice_in_dim(cr, k_rope, slot, axis=2)
-        cpos = jax.lax.dynamic_update_slice_in_dim(
-            cpos, positions.astype(jnp.int32), slot, axis=0)
-        new_cache = {"c_kv": cc, "k_rope": cr, "pos": cpos,
+        new_cache = {"c_kv": c_kv, "k_rope": k_rope,
+                     "pos": positions.astype(jnp.int32),
                      "cursor": jnp.asarray(slot + s, jnp.int32)}
-        o = attend_latent(q_nope, q_rope, cc, cr[:, 0],
+        o = attend_latent(q_nope, q_rope, cache["c_kv"], cache["k_rope"][:, 0],
                           wukv.reshape(r, h, nope + vdim), q_pos=positions,
-                          kv_pos=cpos, sm_scale=scale)
+                          kv_pos=_unwritten(cache["pos"], slot, s),
+                          sm_scale=scale, new=(c_kv, k_rope[:, 0], positions))
     else:
         if mode == "prefill":
             clen = cache_len or s
@@ -509,7 +559,9 @@ def mla_attention(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
 def attend_latent(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
                   c_kv: jnp.ndarray, k_rope: jnp.ndarray, wukv: jnp.ndarray,
                   *, q_pos: jnp.ndarray, kv_pos: jnp.ndarray,
-                  sm_scale: float) -> jnp.ndarray:
+                  sm_scale: float,
+                  new: Optional[Tuple[jnp.ndarray, ...]] = None
+                  ) -> jnp.ndarray:
     """A decode step's MLA over the latent cache, never expanding K or V.
 
     q_nope (B,H,S,nope), q_rope (B,H,S,rope); c_kv (B,L,r) and k_rope
@@ -520,7 +572,9 @@ def attend_latent(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     latent output that the value half of ``wukv`` expands.  Every
     contraction reads the cache once in its own dtype and accumulates in
     f32; the f32 probabilities enter as exact pieces in the cache's dtype
-    (``_exact_pieces``), as in ``attend_decode``.
+    (``_exact_pieces``), as in ``attend_decode``.  ``new`` = (c_kv,
+    k_rope, pos) are the step's own entries, which join the cache's in
+    one softmax, as there.
     """
     from repro.kernels import dispatch
 
@@ -530,22 +584,25 @@ def attend_latent(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     with jax.named_scope("mla.latent"):
         q_lat = jnp.einsum("bhsn,rhn->bhsr", q_nope, wukv[..., :nope],
                            preferred_element_type=jnp.float32).astype(dt)
-        scores = (jnp.einsum("bhsr,blr->bhsl", q_lat, c_kv,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bhsd,bld->bhsl", q_rope.astype(dt), k_rope,
-                               preferred_element_type=jnp.float32)) * sm_scale
-        allow = _mask_for_chunk(q_pos, kv_pos, True, None)[None, None]
-        scores = jnp.where(allow, scores, -1e30)
-        m = jnp.max(scores, axis=-1, keepdims=True)
-        p = jnp.where(allow, jnp.exp(scores - m), 0.0)
-        denom = jnp.sum(p, axis=-1, keepdims=True)
-        pieces = _exact_pieces(p, dt)                   # (n,B,H,S,L)
-        n = pieces.shape[0]
-        pieces = jnp.moveaxis(pieces, 0, 1).reshape(b, n * h, s, -1)
-        o_lat = jnp.einsum("bhsl,blr->bhsr", pieces, c_kv,
-                           preferred_element_type=jnp.float32)
-        o_lat = o_lat.reshape(b, n, h, s, -1).sum(axis=1)
-        o_lat = (o_lat / jnp.maximum(denom, 1e-30)).astype(dt)
+        blocks = [(c_kv, k_rope, kv_pos)] + ([new] if new is not None else [])
+        scores = [((jnp.einsum("bhsr,blr->bhsl", q_lat, c,
+                                preferred_element_type=jnp.float32)
+                    + jnp.einsum("bhsd,bld->bhsl", q_rope.astype(dt), kr,
+                                 preferred_element_type=jnp.float32))
+                   * sm_scale,
+                   _mask_for_chunk(q_pos, pos, True, None)[None, None])
+                  for c, kr, pos in blocks]
+        ps, denom = _joint_softmax(scores)
+        outs = []
+        for (c, _, _), p in zip(blocks, ps):
+            pieces = _exact_pieces(p, dt)                   # (n,B,H,S,L)
+            n = pieces.shape[0]
+            pieces = jnp.moveaxis(pieces, 0, 1).reshape(b, n * h, s, -1)
+            o_lat = jnp.einsum("bhsl,blr->bhsr", pieces, c,
+                               preferred_element_type=jnp.float32)
+            outs.append(o_lat.reshape(b, n, h, s, -1).sum(axis=1))
+        o_lat = functools.reduce(jnp.add, outs) / jnp.maximum(denom, 1e-30)
+        o_lat = o_lat.astype(dt)
         o = jnp.einsum("bhsr,rhv->bhsv", o_lat, wukv[..., nope:],
                        preferred_element_type=jnp.float32)
     return o.astype(q_nope.dtype)

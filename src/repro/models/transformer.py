@@ -26,7 +26,7 @@ from . import ssm as ssm_mod
 from . import xlstm as xlstm_mod
 from .layers import (Params, _dense_init, gqa_attention, init_attention,
                      init_mla, init_mlp, init_rmsnorm, mla_attention, mlp,
-                     rms_norm)
+                     rms_norm, write_slot)
 
 _ZERO_METRICS = ("moe_aux_loss", "router_z_loss", "moe_dropped_frac")
 
@@ -177,6 +177,13 @@ def init_group_cache(cfg: ModelConfig, batch: int, cache_len: int,
     return out
 
 
+def write_layers(cache: Params, entries: Params) -> Params:
+    """Layer caches ``{name: {"mixer": ...}}`` with a decode step's
+    entries written in (``layers.write_slot``)."""
+    return {name: {"mixer": write_slot(c["mixer"], entries[name]["mixer"])}
+            for name, c in cache.items()}
+
+
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype) -> Params:
     """Zero decode cache as ``apply_lm`` takes it: the scanned groups'
     stacked on a leading axis, and the leading dense layers' one entry
@@ -212,6 +219,11 @@ _grad_transparent_barrier.defvjp(_gtb_fwd, _gtb_bwd)
 def apply_stack(stacked: Params, cfg: ModelConfig, x, *, mode: str,
                 caches=None, positions=None, enc_out=None, causal=True,
                 cache_len: int = 0):
+    """The scanned groups.  Prefill returns each group's new cache as the
+    scan's output.  Decode scans the stacked cache read-only; the scan's
+    output is each layer's step entries, written into the stacked cache
+    after the scan, one slot of every layer at once (``write_layers``):
+    no layer's cache is copied out of the stack or back into it."""
     def body(carry, inp):
         x, aux = carry
         # barrier: stops XLA hoisting the bf16→f32 norm upcast out of the
@@ -241,6 +253,10 @@ def apply_stack(stacked: Params, cfg: ModelConfig, x, *, mode: str,
     xs = stacked if caches is None else (stacked, caches)
     (x, aux), new_caches = jax.lax.scan(
         body, (x, aux0), xs, unroll=True if cfg.scan_unroll else 1)
+    if mode == "decode" and caches is not None:
+        from repro.kernels import dispatch
+        dispatch.record("decode_cache", "in_place")
+        new_caches = write_layers(caches, new_caches)
     return x, new_caches, aux
 
 
@@ -332,6 +348,8 @@ def apply_lm(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
                 positions=positions, cache_len=cache_len)
             if nc is not None:
                 new_dense[name] = nc
+        if mode == "decode":
+            new_dense = write_layers(cache["dense"], new_dense)
 
     groups_cache = cache["groups"] if cache is not None else None
     x, new_groups, aux = apply_stack(

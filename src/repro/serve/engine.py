@@ -69,7 +69,9 @@ class Engine:
         self.scfg = serve_cfg
         self._prefill = jax.jit(make_prefill_step(self.cfg,
                                                   serve_cfg.max_len))
-        self._decode = jax.jit(self._decode_fn)
+        # the cache is donated: a step writes its slot into the buffer it
+        # was given, and nothing reads a cache once it is passed on
+        self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
 
     def _decode_fn(self, params, cache, token, pos, rng, counts):
         rng, sub = jax.random.split(rng)

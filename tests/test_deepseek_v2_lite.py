@@ -205,7 +205,8 @@ def test_mla_decode_step_holds_no_expanded_cache():
     """One decode step at DeepSeek-V2-Lite's attention widths (16 heads,
     latent 512, rope 64, nope and v 128) lowers with no array of shape
     (B, H, L, ·) — no K or V expanded from the cache — and no f32 copy of
-    the latent cache, and counts the latent path in both layers."""
+    the latent cache, and counts the latent path in both layers and the
+    scanned stack's in-place cache write once."""
     full = get_config("deepseek-v2-lite")
     cfg = dataclasses.replace(full, n_layers=2, vocab_size=256, d_ff=256,
                               moe_d_ff=64, n_experts=8, experts_held=(0, 2))
@@ -221,6 +222,7 @@ def test_mla_decode_step_holds_no_expanded_cache():
     dispatch.reset_counts()
     text = jax.jit(step).lower(params, cache, token).as_text()
     assert dispatch.counts() == {"decode_attention": {"latent": 2},
+                                 "decode_cache": {"in_place": 1},
                                  "moe": {"held_dropless": 1}}
     dispatch.reset_counts()
     assert re.search(rf"tensor<{b}x{l}x{r}xbf16>", text)     # the cache

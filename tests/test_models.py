@@ -126,10 +126,88 @@ def test_attend_decode_matches_repeat_path(hq, hkv, cache):
     assert rel <= 1e-5, rel
 
 
+@pytest.mark.parametrize("cache", ["linear", "ring"])
+@pytest.mark.parametrize("path", ["grouped", "latent"])
+def test_decode_step_entries_join_the_cache_softmax(path, cache):
+    """A decode step's own entries, given beside the cache, enter the same
+    max-subtract, exp and sum as the cache's: the output equals attending
+    over the cache with them written into the slot they take, to f32
+    rounding, and whatever that slot held before is not read."""
+    from repro.models.layers import _unwritten, attend_latent
+
+    b, l, slot = 2, 48, 40
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    bf = lambda k, shape: jax.random.normal(k, shape).astype(jnp.bfloat16)
+    # positions: a linear cache filled up to the slot, or a ring of l slots
+    # (no window) whose slot holds the position one turn back, which the
+    # step would still see if it were not overwritten
+    kv_pos = jnp.arange(l, dtype=jnp.int32)
+    if cache == "linear":
+        kv_pos = jnp.where(kv_pos < slot, kv_pos, -1)
+        q_pos = jnp.array([slot], jnp.int32)
+    else:
+        kv_pos = jnp.where(kv_pos <= slot, kv_pos + l, kv_pos)
+        q_pos = jnp.array([slot + l], jnp.int32)
+    written_pos = kv_pos.at[slot].set(q_pos[0])
+    if path == "grouped":
+        hq, hkv, d = 15, 5, 64
+        q = bf(ks[0], (b, hq, 1, d)).astype(jnp.float32)
+        k, v = bf(ks[1], (b, hkv, l, d)), bf(ks[2], (b, hkv, l, d))
+        kn, vn = bf(ks[3], (b, hkv, 1, d)), bf(ks[4], (b, hkv, 1, d))
+        want = attend_decode(q, k.at[:, :, slot].set(kn[:, :, 0]),
+                             v.at[:, :, slot].set(vn[:, :, 0]), q_pos=q_pos,
+                             kv_pos=written_pos)
+        got = attend_decode(q, k, v, q_pos=q_pos,
+                            kv_pos=_unwritten(kv_pos, slot, 1),
+                            new=(kn, vn, q_pos))
+    else:
+        h, nope, rope, r, dv = 4, 16, 8, 32, 16
+        qn = bf(ks[0], (b, h, 1, nope)).astype(jnp.float32)
+        qr = bf(ks[5], (b, h, 1, rope)).astype(jnp.float32)
+        c, kr = bf(ks[1], (b, l, r)), bf(ks[2], (b, l, rope))
+        cn, krn = bf(ks[3], (b, 1, r)), bf(ks[4], (b, 1, rope))
+        w = bf(jax.random.PRNGKey(8), (r, h, nope + dv))
+        want = attend_latent(qn, qr, c.at[:, slot].set(cn[:, 0]),
+                             kr.at[:, slot].set(krn[:, 0]), w, q_pos=q_pos,
+                             kv_pos=written_pos, sm_scale=0.1)
+        got = attend_latent(qn, qr, c, kr, w, q_pos=q_pos,
+                            kv_pos=_unwritten(kv_pos, slot, 1), sm_scale=0.1,
+                            new=(cn, krn, q_pos))
+    rel = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    assert rel <= 1e-5, rel
+
+
+@pytest.mark.parametrize("slot", [0, 150, 299])
+def test_write_slot_writes_only_its_slot(slot):
+    """``write_slot`` writes a layer stack's entries at the cursor's slot
+    of each positional leaf, on its own slot axis, and nothing else, and
+    replaces every other leaf whole."""
+    from repro.models.layers import write_slot
+
+    g, b, h, l = 2, 2, 3, 300
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    cache = {"k": jax.random.normal(ks[0], (g, b, h, l, 64)),
+             "c_kv": jax.random.normal(ks[1], (g, b, l, 128)),
+             "pos": jnp.zeros((g, l), jnp.int32),
+             "cursor": jnp.full((g,), slot, jnp.int32)}
+    new = {"k": jax.random.normal(ks[2], (g, b, h, 1, 64)),
+           "c_kv": jax.random.normal(ks[3], (g, b, 1, 128)),
+           "pos": jnp.full((g, 1), 7, jnp.int32),
+           "cursor": jnp.full((g,), slot + 1, jnp.int32)}
+    got = jax.jit(write_slot)(cache, new)
+    for key in ("k", "c_kv", "pos"):
+        want = cache[key].at[..., slot, :] if key != "pos" else \
+            cache[key].at[:, slot]
+        want = want.set(new[key][..., 0, :] if key != "pos"
+                        else new[key][:, 0])
+        np.testing.assert_array_equal(got[key], want)
+    np.testing.assert_array_equal(got["cursor"], new["cursor"])
+
+
 def test_decode_step_holds_no_repeated_or_f32_cache():
     """One decode step of a one-layer model at SmolLM's head widths lowers
     with no KV head repeat and no f32 copy of the cache, and counts the
-    grouped path once."""
+    grouped path and the in-place cache write once."""
     cfg = dataclasses.replace(get_config("smollm-360m"), n_layers=1,
                               vocab_size=256)
     b, l = 2, 40
@@ -145,7 +223,8 @@ def test_decode_step_holds_no_repeated_or_f32_cache():
 
     dispatch.reset_counts()
     text = jax.jit(step).lower(params, cache, token).as_text()
-    assert dispatch.counts() == {"decode_attention": {"grouped": 1}}
+    assert dispatch.counts() == {"decode_attention": {"grouped": 1},
+                                 "decode_cache": {"in_place": 1}}
     g = hq // hkv
 
     def has(dims, dtype=r"\w+"):
@@ -230,34 +309,58 @@ def _step_by_step(cfg, params, prompts, n, max_len, temperature, eos, rng):
     return np.concatenate(out, axis=1)
 
 
-@pytest.mark.parametrize("temperature,stop", [(0.0, False), (1.0, False),
-                                              (0.0, True)],
-                         ids=["greedy", "sampled", "eos"])
-def test_generate_matches_step_by_step_decode(temperature, stop):
-    """``Engine.generate`` reads each token one step behind the device and
-    splits the key inside the step: it serves the tokens of a loop that
-    splits on the host and reads every step, and stops after the same
-    token when every row reaches ``eos_id``."""
+#: reduced configs of ``test_generate_matches_step_by_step_decode``: a
+#: dense KV cache, MLA's latent cache beside a leading dense layer's with
+#: held experts, a sliding-window ring and the int8 cache with its scales
+_GENERATE_MODELS = {
+    "dense": lambda: reduced_config(get_config("smollm-360m")),
+    "mla_moe": lambda: reduced_config(get_config("deepseek-v2-lite")),
+    "ring": lambda: dataclasses.replace(
+        reduced_config(get_config("mixtral-8x7b")), capacity_factor=8.0,
+        window=8),
+    "int8": lambda: dataclasses.replace(
+        reduced_config(get_config("smollm-360m")), kv_quant=True),
+}
+
+
+@pytest.mark.parametrize(
+    "temperature,stop,model,calls",
+    [(0.0, False, "dense", 1), (1.0, False, "dense", 1),
+     (0.0, True, "dense", 1), (0.0, False, "mla_moe", 1),
+     (0.0, False, "ring", 1), (0.0, False, "int8", 1),
+     (1.0, False, "dense", 2)],
+    ids=["greedy", "sampled", "eos", "mla_moe", "ring", "int8", "twice"])
+def test_generate_matches_step_by_step_decode(temperature, stop, model,
+                                              calls):
+    """``Engine.generate`` reads each token one step behind the device,
+    splits the key inside the step and donates the cache to it: it serves
+    the tokens of a loop that splits on the host, reads every step and
+    donates nothing, and stops after the same token when every row
+    reaches ``eos_id``.  So do a ring that wraps (fewer slots than the
+    call's positions), the latent cache, the int8 cache, and a second
+    call on one engine, which must find no donated buffer read again."""
     from repro.serve.engine import Engine, ServeConfig
 
-    cfg = dataclasses.replace(reduced_config(get_config("smollm-360m")),
-                              dtype="float32")
+    cfg = dataclasses.replace(_GENERATE_MODELS[model](), dtype="float32")
     params = T.init_lm(jax.random.PRNGKey(31), cfg)
     b, s, n = 1, 12, 10
+    max_len = cfg.window or s + n
     prompts = jax.random.randint(jax.random.PRNGKey(32), (b, s), 1,
                                  cfg.vocab_size)
     rng = jax.random.PRNGKey(33)
-    want = _step_by_step(cfg, params, prompts, n, s + n, temperature, -1,
+    want = _step_by_step(cfg, params, prompts, n, max_len, temperature, -1,
                          rng)
     eos = int(want[0, 4]) if stop else -1
     if stop:
-        want = _step_by_step(cfg, params, prompts, n, s + n, temperature,
+        want = _step_by_step(cfg, params, prompts, n, max_len, temperature,
                              eos, rng)
         assert want.shape[1] <= 5
-    eng = Engine(cfg, params, ServeConfig(max_len=s + n,
+    eng = Engine(cfg, params, ServeConfig(max_len=max_len,
                                           temperature=temperature,
                                           eos_id=eos))
-    np.testing.assert_array_equal(eng.generate(prompts, n, rng=rng), want)
+    for _ in range(calls):
+        np.testing.assert_array_equal(eng.generate(prompts, n, rng=rng),
+                                      want)
 
 
 def test_param_count_analytic_close_to_actual():
@@ -312,9 +415,11 @@ def test_mla_absorbed_decode_matches_naive():
     absorbed, _, _ = T.apply_lm(params, cfg, tokens[:, -1:], mode="decode",
                                 cache=cache,
                                 positions=jnp.array([s - 1], jnp.int32))
-    # one traced call per layer of the scanned group
+    # one traced call per layer of the scanned group, one in-place write
+    # of the stacked cache
     assert dispatch.counts() == {"decode_attention":
-                                 {"latent": cfg.group_size}}
+                                 {"latent": cfg.group_size},
+                                 "decode_cache": {"in_place": 1}}
     dispatch.reset_counts()
     a, e = np.asarray(absorbed[:, 0]), np.asarray(full[:, -1])
     # bf16 activations: the latent and the expanded form round at
